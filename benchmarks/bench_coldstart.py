@@ -27,6 +27,12 @@ loaded CI box is not a latency lab) while keeping every deterministic
 assertion: warm runs must hit on every load and never trace.  The
 committed full-run artifact (BENCH_coldstart.json) carries the <1s
 claim.
+
+The parent never imports JAX: a process that has touched JAX holds the
+accelerator, and the children need it.  The first child of each
+scenario initializes the parameters and writes the checkpoint; each
+child reports the environment fingerprint the BENCH file is stamped
+with.
 """
 from __future__ import annotations
 
@@ -88,13 +94,13 @@ def child(state_path: str) -> None:
     the parent parses; everything else is free-form."""
     with open(state_path) as f:
         state = json.load(f)
+    if not os.path.exists(state["blob"]):
+        _checkpoint(state["tenants"], state["reduced"], state["blob"])
     with open(state["blob"], "rb") as f:
         blob = pickle.load(f)
 
-    import numpy as np
-
     from repro.core.batching import BucketBudget, pack_prepared
-    from repro.serve.aot import AOTCache, XlaFlagConfig
+    from repro.serve.aot import AOTCache, XlaFlagConfig, environment_fingerprint
     from repro.serve.executor import Executor
     from repro.serve.scheduler import StreamScheduler
 
@@ -137,6 +143,7 @@ def child(state_path: str) -> None:
         "lowered": ex.lowered_count,
         "compile_s": round(ex.compile_seconds, 4),
         "warm_s": round(ex.warm_seconds, 4),
+        "env": environment_fingerprint(),
     }))
 
 
@@ -166,9 +173,10 @@ def _spawn(state: dict, workdir: str) -> dict:
     return out
 
 
-def _checkpoint(tenants, reduced, workdir, n_graphs=8) -> str:
+def _checkpoint(tenants, reduced, blob, n_graphs=8) -> None:
     """Init params once, save as a numpy checkpoint — the realistic
-    restart loads weights from disk instead of re-running jitted init."""
+    restart loads weights from disk instead of re-running jitted init.
+    Runs in the first child of a scenario, never in the parent."""
     import jax
     import numpy as np
 
@@ -178,10 +186,8 @@ def _checkpoint(tenants, reduced, workdir, n_graphs=8) -> str:
     for i, t in enumerate(tenants):
         tree = init(jax.random.PRNGKey(i), _cfg(t["model"], reduced))
         params[t["name"]] = jax.tree_util.tree_map(np.asarray, tree)
-    blob = os.path.join(workdir, "checkpoint.pkl")
     with open(blob, "wb") as f:
         pickle.dump({"params": params, "graphs": _graphs(n_graphs)}, f)
-    return blob
 
 
 def run(smoke: bool, strict: bool):
@@ -197,7 +203,7 @@ def run(smoke: bool, strict: bool):
             ("multitenant_autotuned", multi, "table"),
         ]
         for label, tenants, flags in scenarios:
-            blob = _checkpoint(tenants, smoke, workdir)
+            blob = os.path.join(workdir, f"checkpoint_{label}.pkl")
             cache_dir = os.path.join(workdir, f"cache_{label}")
             state = {"blob": blob, "cache_dir": cache_dir, "flags": flags,
                      "tenants": tenants, "reduced": smoke}
@@ -250,11 +256,15 @@ def main(strict: bool = False):
         return []
     smoke = "--smoke" in sys.argv
     rows = run(smoke=smoke, strict=strict or smoke)
+    env = dict(rows[0]["derived"]["env"])
+    env.pop("schema", None)
+    env.pop("flags", None)  # per-program, not per-environment
     write_bench_json("coldstart_smoke" if smoke else "coldstart", rows,
                      config={"argv": sys.argv[1:], "capacity": CAPACITY,
                              "steady_reps": STEADY_REPS,
                              "warm_first_request_limit_s":
-                                 30.0 if smoke else 1.0})
+                                 30.0 if smoke else 1.0},
+                     env=env)
     return rows
 
 

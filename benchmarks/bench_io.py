@@ -34,7 +34,10 @@ def _environment() -> dict:
 
 
 def write_bench_json(name: str, metrics, config: dict | None = None,
-                     out_dir: str | None = None) -> str:
+                     out_dir: str | None = None, env: dict | None = None) -> str:
+    """Write one BENCH file.  ``env`` stamps an environment probed
+    elsewhere (a bench whose parent must not touch JAX passes its
+    child's); by default this process probes its own."""
     out_dir = out_dir or os.environ.get("REPRO_BENCH_DIR", ".")
     os.makedirs(out_dir, exist_ok=True)
     path = os.path.join(out_dir, f"BENCH_{name}.json")
@@ -42,7 +45,7 @@ def write_bench_json(name: str, metrics, config: dict | None = None,
         "name": name,
         "config": config or {},
         "metrics": metrics,
-        "env": _environment(),
+        "env": env if env is not None else _environment(),
         "timestamp": datetime.now(timezone.utc).isoformat(timespec="seconds"),
     }
     with open(path, "w") as f:

@@ -15,50 +15,55 @@ trajectory is tracked across PRs.  Sections:
   layout shared GraphLayout plan: sort counts + stream latency + recompiles
   multitenant  shared Executor vs N separate engines (warm time, programs)
   coldstart  AOT cache: cold vs warm-disk restart (subprocess), flag deltas
+             — runs alone: its children need the accelerator, which a
+             process that has already used JAX holds
   roofline  per-(arch x shape x mesh) dry-run roofline terms
+
+The default runs every section but ``coldstart``.
 """
+import importlib
 import sys
+
+_MODULES = {
+    "fig7": "bench_fig7_latency",
+    "fig8": "bench_fig8_large_graph",
+    "fig9": "bench_fig9_pipeline",
+    "table4": "bench_table4_resources",
+    "stream": "bench_stream_throughput",
+    "slo": "bench_slo",
+    "pipeline": "bench_pipeline",
+    "quant": "bench_quant",
+    "layout": "bench_layout",
+    "multitenant": "bench_multitenant",
+    "coldstart": "bench_coldstart",
+    "roofline": "bench_roofline",
+}
 
 
 def main() -> None:
     sections = sys.argv[1:] or [
         "fig9", "table4", "fig8", "fig7", "stream", "slo", "pipeline",
-        "quant", "layout", "multitenant", "coldstart", "roofline"
+        "quant", "layout", "multitenant", "roofline"
     ]
-    from benchmarks import (
-        bench_coldstart,
-        bench_fig7_latency,
-        bench_fig8_large_graph,
-        bench_fig9_pipeline,
-        bench_layout,
-        bench_multitenant,
-        bench_pipeline,
-        bench_quant,
-        bench_roofline,
-        bench_slo,
-        bench_stream_throughput,
-        bench_table4_resources,
-    )
+    unknown = [s for s in sections if s not in _MODULES]
+    if unknown:
+        raise SystemExit(f"unknown sections {unknown}; choose from "
+                         f"{sorted(_MODULES)}")
+    if "coldstart" in sections and len(sections) > 1:
+        raise SystemExit("coldstart runs alone: its restarted children "
+                         "need the accelerator, and the other sections "
+                         "would hold it in this process")
+    if sections != ["coldstart"]:
+        from repro.runtime import configure_compilation_cache
+
+        configure_compilation_cache()
     from benchmarks.bench_io import write_bench_json
 
-    mods = {
-        "fig7": bench_fig7_latency,
-        "fig8": bench_fig8_large_graph,
-        "fig9": bench_fig9_pipeline,
-        "table4": bench_table4_resources,
-        "stream": bench_stream_throughput,
-        "slo": bench_slo,
-        "pipeline": bench_pipeline,
-        "quant": bench_quant,
-        "layout": bench_layout,
-        "multitenant": bench_multitenant,
-        "coldstart": bench_coldstart,
-        "roofline": bench_roofline,
-    }
     for s in sections:
+        mod = importlib.import_module(f"benchmarks.{_MODULES[s]}")
         print(f"# --- {s} ---", flush=True)
-        rows = mods[s].main()
-        if rows and not getattr(mods[s], "WRITES_OWN_BENCH", False):
+        rows = mod.main()
+        if rows and not getattr(mod, "WRITES_OWN_BENCH", False):
             write_bench_json(s, rows, config={"argv": sys.argv[1:]})
 
 

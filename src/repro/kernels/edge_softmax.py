@@ -24,6 +24,19 @@ import jax.numpy as jnp
 from repro.kernels.segment_reduce import segment_reduce_sorted
 
 
+def softmax_over_segments(reduce, logits, segment_ids, num_segments: int):
+    """The edge softmax with ``reduce(values, ids, num_segments, op)`` as
+    its segment reduction (``kernels.ops`` passes its mesh-aware one)."""
+    valid = segment_ids < num_segments
+    seg_max = reduce(logits, segment_ids, num_segments, "max")
+    ids_safe = jnp.minimum(segment_ids, num_segments - 1)
+    shifted = logits.astype(jnp.float32) - seg_max[ids_safe]
+    z = jnp.where(valid[:, None], jnp.exp(shifted), 0.0)
+    seg_sum = reduce(z, segment_ids, num_segments, "sum")
+    w = z / jnp.maximum(seg_sum[ids_safe], 1e-30)
+    return jnp.where(valid[:, None], w, 0.0).astype(logits.dtype)
+
+
 @functools.partial(jax.jit, static_argnames=("num_segments", "interpret"))
 def edge_softmax(
     logits: jax.Array,
@@ -32,15 +45,5 @@ def edge_softmax(
     interpret: bool = False,
 ) -> jax.Array:
     """logits: (E, H) sorted by segment; returns per-segment softmax weights."""
-    valid = segment_ids < num_segments
-    seg_max = segment_reduce_sorted(
-        logits, segment_ids, num_segments, op="max", interpret=interpret
-    )
-    ids_safe = jnp.minimum(segment_ids, num_segments - 1)
-    shifted = logits.astype(jnp.float32) - seg_max[ids_safe]
-    z = jnp.where(valid[:, None], jnp.exp(shifted), 0.0)
-    seg_sum = segment_reduce_sorted(
-        z, segment_ids, num_segments, op="sum", interpret=interpret
-    )
-    w = z / jnp.maximum(seg_sum[ids_safe], 1e-30)
-    return jnp.where(valid[:, None], w, 0.0).astype(logits.dtype)
+    reduce = functools.partial(segment_reduce_sorted, interpret=interpret)
+    return softmax_over_segments(reduce, logits, segment_ids, num_segments)

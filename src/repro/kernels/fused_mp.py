@@ -31,7 +31,7 @@ paper's pipeline expressed as a single ``pallas_call``:
 The layer contract arrives as a declarative ``core.message_passing.MPSpec``
 (duck-typed: this module never imports ``core``); the pure-jnp oracle is
 ``kernels/ref.fused_mp_ref``; dispatch (backend policy, VMEM budget
-fallback) lives in ``kernels/ops.fused_mp``.
+check) lives in ``kernels/ops.fused_mp``.
 """
 from __future__ import annotations
 
@@ -94,25 +94,23 @@ def _fused_kernel(
             else:
                 acc[...] = jnp.zeros_like(acc)
 
-    ids = ids_ref[...][:, 0]  # (TE,)
     lo = i * tn
-    first, last = ids[0], ids[-1]
+    # static block bounds: ``ids[-1]`` lowers to dynamic_slice, which the
+    # TPU lowering rejects
+    first, last = ids_ref[0, 0], ids_ref[te - 1, 0]
     overlap = (first < lo + tn) & (last >= lo) & (first < num_segments)
 
     @pl.when(overlap)
     def _accumulate():
         # gather + phi: messages are produced into VMEM scratch and never
-        # leave the chip — the paper's merged scatter-gather
-        src = src_ref[...][:, 0]
+        # leave the chip — the paper's merged scatter-gather.  Per-edge
+        # scalars are read from the refs inside the loops: a dynamic index
+        # into a loaded vector does not lower on the TPU.
         n_rows = msrc_ref.shape[0]
 
         def gather(e, _):
-            s = jnp.clip(src[e], 0, n_rows - 1)
-            pl.store(
-                msg_ref,
-                (pl.ds(e, 1), slice(None)),
-                pl.load(msrc_ref, (pl.ds(s, 1), slice(None))),
-            )
+            s = jnp.clip(src_ref[e, 0], 0, n_rows - 1)
+            msg_ref[pl.ds(e, 1), :] = msrc_ref[pl.ds(s, 1), :]
             return ()
 
         jax.lax.fori_loop(0, te, gather, ())
@@ -120,6 +118,7 @@ def _fused_kernel(
             msg_ref[...] = jnp.maximum(msg_ref[...] + eop_ref[...], 0.0)
         msg = msg_ref[...]
 
+        ids = ids_ref[...][:, 0]  # (TE,)
         local = ids - lo
         onehot = (
             (local[:, None] == jax.lax.iota(jnp.int32, tn)[None, :])
@@ -143,14 +142,14 @@ def _fused_kernel(
                 continue
 
             def extremum(e, _, acc=acc, op=op):
-                row = ids[e] - lo
-                in_block = (row >= 0) & (row < tn) & (ids[e] < num_segments)
+                seg = ids_ref[e, 0]
+                row = seg - lo
+                in_block = (row >= 0) & (row < tn) & (seg < num_segments)
                 safe = jnp.clip(row, 0, tn - 1)
-                cur = pl.load(acc, (pl.ds(safe, 1), slice(None)))
-                val = pl.load(msg_ref, (pl.ds(e, 1), slice(None)))
+                cur = acc[pl.ds(safe, 1), :]
+                val = msg_ref[pl.ds(e, 1), :]
                 new = jnp.maximum(cur, val) if op == "max" else jnp.minimum(cur, val)
-                pl.store(acc, (pl.ds(safe, 1), slice(None)),
-                         jnp.where(in_block, new, cur))
+                acc[pl.ds(safe, 1), :] = jnp.where(in_block, new, cur)
                 return ()
 
             jax.lax.fori_loop(0, te, extremum, ())
@@ -248,7 +247,9 @@ def fused_mp(
     src2d = src_sorted.astype(jnp.int32).reshape(e_pad, 1)
     deg2d = _pad_rows(in_degree.astype(jnp.float32).reshape(n, 1), n_pad)
     mask2d = _pad_rows(node_mask.astype(jnp.float32).reshape(n, 1), n_pad)
-    msrc = _pad_rows(msrc.astype(jnp.float32), n_pad)
+    # the source table may hold more rows than the nodes updated here
+    # (a mesh shard updates its own rows from the whole table)
+    msrc = _pad_rows(msrc.astype(jnp.float32), max(msrc.shape[0], n_pad))
     x_res = _pad_rows(x_res.astype(jnp.float32), n_pad)
     nop = (
         jnp.zeros((n_pad, 1), jnp.float32) if nop is None

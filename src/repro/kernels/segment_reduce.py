@@ -45,7 +45,10 @@ def _kernel_matmul(ids_ref, vals_ref, out_ref, *, tn: int, op: str, num_segments
 
     ids = ids_ref[...][:, 0]  # (TE,)
     lo = i * tn
-    first, last = ids[0], ids[-1]
+    te = ids.shape[0]
+    # static block bounds: ``ids[-1]`` lowers to dynamic_slice, which the
+    # TPU lowering rejects
+    first, last = ids_ref[0, 0], ids_ref[te - 1, 0]
     overlap = (first < lo + tn) & (last >= lo) & (first < num_segments)
 
     @pl.when(overlap)
@@ -76,31 +79,24 @@ def _kernel_extremum(ids_ref, vals_ref, out_ref, *, tn: int, op: str, num_segmen
     def _init():
         out_ref[...] = jnp.full_like(out_ref, fill)
 
-    ids = ids_ref[...][:, 0]
     lo = i * tn
-    te = ids.shape[0]
-    first, last = ids[0], ids[-1]
+    te = ids_ref.shape[0]
+    first, last = ids_ref[0, 0], ids_ref[te - 1, 0]
     overlap = (first < lo + tn) & (last >= lo) & (first < num_segments)
 
     @pl.when(overlap)
     def _accumulate():
-        vals = vals_ref[...].astype(jnp.float32)
-
         def body(e, _):
-            row = ids[e] - lo
-            in_block = (row >= 0) & (row < tn) & (ids[e] < num_segments)
+            # per-edge scalars and rows come straight from the refs: a
+            # dynamic index into a loaded vector does not lower on the TPU
+            seg = ids_ref[e, 0]
+            row = seg - lo
+            in_block = (row >= 0) & (row < tn) & (seg < num_segments)
             safe = jnp.clip(row, 0, tn - 1)
-            cur = pl.load(out_ref, (pl.ds(safe, 1), slice(None)))
-            new = (
-                jnp.maximum(cur, vals[e][None, :])
-                if op == "max"
-                else jnp.minimum(cur, vals[e][None, :])
-            )
-            pl.store(
-                out_ref,
-                (pl.ds(safe, 1), slice(None)),
-                jnp.where(in_block, new, cur),
-            )
+            cur = out_ref[pl.ds(safe, 1), :]
+            val = vals_ref[pl.ds(e, 1), :].astype(jnp.float32)
+            new = jnp.maximum(cur, val) if op == "max" else jnp.minimum(cur, val)
+            out_ref[pl.ds(safe, 1), :] = jnp.where(in_block, new, cur)
             return ()
 
         jax.lax.fori_loop(0, te, body, ())

@@ -1,7 +1,10 @@
 """Serving launcher: batched prefill+decode for LM archs, or the streaming
 GNN engine for the paper's models.
 
-Examples (CPU, reduced configs):
+On the CPU set ``JAX_PLATFORMS=cpu``; otherwise the launcher exits unless
+JAX finds a TPU (``runtime.compat.require_tpu``).
+
+Examples (reduced configs):
   PYTHONPATH=src python -m repro.launch.serve --arch chatglm3-6b --reduced
   PYTHONPATH=src python -m repro.launch.serve --gnn gin --n-graphs 32
   PYTHONPATH=src python -m repro.launch.serve --gnn gin --stream \
@@ -378,6 +381,10 @@ def main():
                          "ap_fixed<W,I> emulation")
     args = ap.parse_args()
     args._t0 = t0
+    from repro.runtime import configure_compilation_cache, require_tpu
+
+    require_tpu()
+    configure_compilation_cache()
     if args.models:
         serve_gnn_multitenant(args)
     elif args.gnn:

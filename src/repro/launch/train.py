@@ -5,8 +5,9 @@ rules.  On this CPU container it runs reduced configs on a debug mesh
 (``--debug-mesh``); on a real pod slice the same code path runs the full
 mesh (the dry-run proves every full config lowers & compiles).
 
-Example (CPU):
-  PYTHONPATH=src python -m repro.launch.train --arch rwkv6-1.6b \
+Example (CPU; without ``JAX_PLATFORMS=cpu`` the launcher exits unless JAX
+finds a TPU):
+  JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.train --arch rwkv6-1.6b \
       --reduced --steps 20 --batch 4 --seq 64
 """
 import argparse
@@ -42,6 +43,8 @@ def main():
     ap.add_argument("--rules", default="default", choices=("default", "fsdp"),
                     help="sharding preset (fsdp = EXPERIMENTS.md §Perf H1 winner)")
     args = ap.parse_args()
+    RT.require_tpu()
+    RT.configure_compilation_cache()
 
     cfg = (get_reduced if args.reduced else get_config)(args.arch)
     mesh = None

@@ -108,7 +108,7 @@ CATALOG: Dict[str, Tuple[str, str, Tuple[str, ...]]] = {
     "kernels_dispatch_total": (
         "counter",
         "Kernel dispatch decisions at trace time, by op and resolved path "
-        "(kernel|interpret|reference|vmem_fallback)",
+        "(kernel|interpret|reference)",
         ("op", "path")),
 }
 

@@ -1,9 +1,10 @@
-"""repro.runtime — the version-portable execution substrate.
+"""repro.runtime — the execution substrate.
 
 One import surface for everything mesh/sharding related:
 
-  * ``compat``       — feature-detected JAX mesh API (make_mesh, shard_map,
-                       use_mesh, get_active_mesh)
+  * ``compat``       — the JAX mesh API (make_mesh, shard_map, use_mesh,
+                       get_active_mesh), executable serialization and the
+                       persistent compilation cache
   * ``mesh``         — production / debug / flat mesh builders
   * ``partitioning`` — logical-axis rules, PartitionSpec resolution,
                        logical_constraint, sharded message passing
@@ -14,11 +15,11 @@ import paths (``repro.sharding``, ``repro.launch.mesh``,
 """
 from repro.runtime import compat, mesh, partitioning
 from repro.runtime.compat import (
-    HAS_SERIALIZE_EXECUTABLE,
+    configure_compilation_cache,
     deserialize_compiled,
-    enable_compilation_cache,
     get_active_mesh,
     make_mesh,
+    require_tpu,
     serialize_compiled,
     shard_map,
     use_mesh,
@@ -50,11 +51,11 @@ __all__ = [
     "compat",
     "mesh",
     "partitioning",
-    "HAS_SERIALIZE_EXECUTABLE",
+    "configure_compilation_cache",
     "deserialize_compiled",
-    "enable_compilation_cache",
     "get_active_mesh",
     "make_mesh",
+    "require_tpu",
     "serialize_compiled",
     "shard_map",
     "use_mesh",

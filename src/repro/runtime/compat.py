@@ -1,52 +1,79 @@
-"""Version-portable facade over JAX's moving mesh/sharding API surface.
+"""One facade over JAX's mesh/sharding, executable-serialization and
+compilation-cache surfaces.
 
-The mesh API has churned across JAX releases:
+Everything in ``repro`` that needs a mesh goes through this module:
+``make_mesh`` (Auto axis types), ``shard_map`` (the public
+``jax.shard_map``), ``use_mesh`` (``jax.set_mesh``) and
+``get_active_mesh`` (``jax.sharding.get_abstract_mesh``).
 
-  * ``jax.make_mesh`` grew an ``axis_types`` kwarg (with
-    ``jax.sharding.AxisType``) after 0.4.x,
-  * ``jax.shard_map`` moved out of ``jax.experimental.shard_map`` and
-    renamed ``check_rep`` to ``check_vma``,
-  * the "current mesh" moved from the thread-local ``with mesh:`` resource
-    env to ``jax.set_mesh`` / ``jax.sharding.get_abstract_mesh()``.
+It also wraps the **executable serialization** API that the persistent
+AOT compile cache (``serve/aot.py``) builds on:
+``jax.experimental.serialize_executable`` round-trips a
+``Lowered(...).compile()`` product to bytes and back without retracing
+or recompiling.
 
-Everything in ``repro`` that needs a mesh goes through this module, so the
-same code runs on JAX 0.4.x and newer.  Feature flags are module-level so
-tests can monkeypatch each detection path.
-
-Beyond the mesh surface, this module also probes the **executable
-serialization** API that the persistent AOT compile cache
-(``serve/aot.py``) builds on: ``jax.experimental.serialize_executable``
-round-trips a ``Lowered(...).compile()`` product to bytes and back
-without retracing or recompiling.  Where that API is absent on the
-pinned JAX, :func:`enable_compilation_cache` is the feature-detected
-fallback — it turns on JAX's own on-disk compilation cache, which still
-kills the *compile* half of a restart's warm-up (the trace half stays).
+:func:`configure_compilation_cache` places JAX's own persistent
+compilation cache and :func:`require_tpu` refuses a silent fall-back off
+the chip; every entry point calls both once at start-up.
 """
 from __future__ import annotations
 
 import contextlib
-import contextvars
-import inspect
+import os
 from typing import Callable, Optional
 
 import jax
+from jax.experimental import serialize_executable as _sx
 
-# --------------------------------------------------------------- detection
+# <checkout>/src/repro/runtime/compat.py -> <checkout>
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
 
-HAS_GET_ABSTRACT_MESH = hasattr(jax.sharding, "get_abstract_mesh")
-HAS_SET_MESH = hasattr(jax, "set_mesh")
-HAS_AXIS_TYPES = hasattr(jax.sharding, "AxisType")
-HAS_JAX_SHARD_MAP = hasattr(jax, "shard_map")
+# ------------------------------------------------------ compilation cache
 
-try:  # executable (AOT) serialization — the serve/aot.py fast path
-    from jax.experimental import serialize_executable as _sx
 
-    HAS_SERIALIZE_EXECUTABLE = (
-        hasattr(_sx, "serialize") and hasattr(_sx, "deserialize_and_load")
-    )
-except ImportError:  # pragma: no cover - depends on pinned jax
-    _sx = None
-    HAS_SERIALIZE_EXECUTABLE = False
+def default_compilation_cache_dir() -> str:
+    """The fixed in-checkout cache path used when the environment names
+    none.  Fixed on purpose: the path is part of the cache's key, so a
+    temporary or per-process directory would never hit."""
+    return os.path.join(_CHECKOUT, ".jax_cache")
+
+
+def configure_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its
+    directory.
+
+    When ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    this sets no directory; otherwise the cache goes to
+    :func:`default_compilation_cache_dir`.  Either way every program
+    qualifies for caching (no minimum compile time or entry size), so a
+    second process finds every serving program it compiled before."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = default_compilation_cache_dir()
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    return path
+
+
+# ------------------------------------------------------------ platform
+
+
+def require_tpu() -> str:
+    """The platform JAX runs on, for an entry point that serves or trains.
+
+    Unless ``JAX_PLATFORMS`` names the CPU (tests, CI, the interpret-mode
+    kernels), the program runs on the TPU or not at all: JAX falling back
+    to another platform exits the process with an error rather than
+    serving quietly through the jnp reference path."""
+    platform = jax.devices()[0].platform
+    asked = os.environ.get("JAX_PLATFORMS", "").split(",")
+    if platform != "tpu" and "cpu" not in asked:
+        raise SystemExit(
+            f"no TPU found (JAX runs on {platform!r}); set JAX_PLATFORMS=cpu "
+            f"to run on the CPU on purpose")
+    return platform
 
 
 # ------------------------------------------------- executable serialization
@@ -55,14 +82,7 @@ except ImportError:  # pragma: no cover - depends on pinned jax
 def serialize_compiled(compiled) -> tuple:
     """Serialize one ``jax.stages.Compiled`` to ``(payload_bytes,
     in_tree, out_tree)`` — everything :func:`deserialize_compiled` needs
-    to rebuild a callable executable in another process.  Raises
-    ``RuntimeError`` when the pinned JAX has no serialization API
-    (callers feature-gate on ``HAS_SERIALIZE_EXECUTABLE``)."""
-    if not HAS_SERIALIZE_EXECUTABLE:
-        raise RuntimeError(
-            "jax.experimental.serialize_executable is unavailable on this "
-            "JAX version; gate on runtime.compat.HAS_SERIALIZE_EXECUTABLE"
-        )
+    to rebuild a callable executable in another process."""
     return _sx.serialize(compiled)
 
 
@@ -72,52 +92,28 @@ def deserialize_compiled(payload: bytes, in_tree, out_tree):
     (``serve/aot.py``) is responsible for fingerprinting the environment
     so a payload is never loaded onto a different jax/jaxlib/backend/
     topology than it was compiled for."""
-    if not HAS_SERIALIZE_EXECUTABLE:
-        raise RuntimeError(
-            "jax.experimental.serialize_executable is unavailable on this "
-            "JAX version; gate on runtime.compat.HAS_SERIALIZE_EXECUTABLE"
-        )
     return _sx.deserialize_and_load(payload, in_tree, out_tree)
-
-
-def enable_compilation_cache(path: str) -> bool:
-    """Fallback persistence when executable serialization is absent:
-    point JAX's own on-disk compilation cache at ``path`` (with the
-    min-compile-time/min-entry-size knobs opened so every serving
-    program qualifies).  Returns True when the cache engaged, False when
-    this JAX has no usable compilation-cache config (the caller then
-    runs uncached, exactly as before)."""
-    try:
-        jax.config.update("jax_compilation_cache_dir", path)
-    except Exception:  # noqa: BLE001 - option absent on this version
-        return False
-    for knob, value in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                        ("jax_persistent_cache_min_entry_size_bytes", 0)):
-        try:
-            jax.config.update(knob, value)
-        except Exception:  # noqa: BLE001 - knob absent; cache still works
-            pass
-    return True
 
 
 # ------------------------------------------------------- mesh construction
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with Auto axis types where the concept exists."""
+    """``jax.make_mesh`` with Auto axis types."""
     kw = {"devices": devices} if devices is not None else {}
-    if HAS_AXIS_TYPES:
-        kw["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axis_names)
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kw)
+    return jax.make_mesh(
+        tuple(axis_shapes), tuple(axis_names),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names), **kw,
+    )
 
 
 def mesh_from_devices(devices, axis_names) -> jax.sharding.Mesh:
     """Build a Mesh from an explicit device array (e.g. a flattened view of
     another mesh's devices)."""
-    kw = {}
-    if HAS_AXIS_TYPES:
-        kw["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axis_names)
-    return jax.sharding.Mesh(devices, tuple(axis_names), **kw)
+    return jax.sharding.Mesh(
+        devices, tuple(axis_names),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
+    )
 
 
 # ------------------------------------------------------------- shard_map
@@ -126,81 +122,24 @@ def mesh_from_devices(devices, axis_names) -> jax.sharding.Mesh:
 def shard_map(
     f: Callable, mesh, in_specs, out_specs, check_replication: bool = False
 ):
-    """Portable ``shard_map``: resolves the public-vs-experimental location
-    and the ``check_vma``/``check_rep`` kwarg rename."""
-    if HAS_JAX_SHARD_MAP:
-        sm = jax.shard_map
-        params = inspect.signature(sm).parameters
-        kw = "check_vma" if "check_vma" in params else "check_rep"
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  **{kw: check_replication})
-    from jax.experimental.shard_map import shard_map as sm
-
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=check_replication)
+    """``jax.shard_map`` with replication checking off by default."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_replication)
 
 
 # ------------------------------------------------------------ active mesh
 
-# Our own fallback context: always maintained by use_mesh() so that
-# get_active_mesh() works even where JAX has no queryable mesh state.
-_ACTIVE_MESH: contextvars.ContextVar = contextvars.ContextVar(
-    "repro_runtime_mesh", default=None
-)
-
 
 @contextlib.contextmanager
 def use_mesh(mesh):
-    """Install ``mesh`` as the active mesh for the dynamic extent.
-
-    On new JAX this is ``jax.set_mesh``; on 0.4.x it is the thread-local
-    ``with mesh:`` resource env.  Either way our contextvar mirrors it so
-    ``get_active_mesh()`` has a uniform answer.
-    """
-    token = _ACTIVE_MESH.set(mesh)
-    try:
-        if HAS_SET_MESH:
-            with jax.set_mesh(mesh):
-                yield mesh
-        else:
-            with mesh:
-                yield mesh
-    finally:
-        _ACTIVE_MESH.reset(token)
+    """Install ``mesh`` as the active mesh for the dynamic extent."""
+    with jax.set_mesh(mesh):
+        yield mesh
 
 
-def _native_abstract_mesh():
-    """The new-API answer, or None where absent/empty (split out so tests
-    can exercise both detection branches)."""
-    if not HAS_GET_ABSTRACT_MESH:
-        return None
+def get_active_mesh() -> Optional[object]:
+    """Return the active abstract mesh, or None when no mesh is set."""
     mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty:
         return None
     return mesh
-
-
-def _thread_resources_mesh():
-    """The 0.4.x answer: the ``with mesh:`` thread-local physical mesh."""
-    try:
-        from jax.interpreters import pxla
-
-        mesh = pxla.thread_resources.env.physical_mesh
-        return None if mesh.empty else mesh
-    except Exception:  # noqa: BLE001 — internal layout changed; fall through
-        return None
-
-
-def get_active_mesh() -> Optional[object]:
-    """Return the active (abstract or physical) mesh, or None.
-
-    Resolution order: native get_abstract_mesh -> our use_mesh contextvar
-    -> the 0.4.x thread-resources env.  Never raises on any JAX version.
-    """
-    mesh = _native_abstract_mesh()
-    if mesh is not None:
-        return mesh
-    mesh = _ACTIVE_MESH.get()
-    if mesh is not None:
-        return mesh
-    return _thread_resources_mesh()

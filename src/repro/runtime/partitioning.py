@@ -253,17 +253,11 @@ def logical_constraint(x, axes: Tuple[Optional[str], ...]):
 
 
 def _resolve_num_shards(num_shards: int | None, axis_name: str) -> int:
-    """Static shard count for a mapped axis.  ``jax.lax.axis_size`` only
-    exists on newer JAX, so callers on 0.4.x must pass num_shards (which
-    make_sharded_mp always does, from the mesh)."""
+    """Static shard count for a mapped axis: ``num_shards`` when given
+    (``make_sharded_mp`` passes it from the mesh), else the axis size."""
     if num_shards is not None:
         return int(num_shards)
-    if hasattr(jax.lax, "axis_size"):
-        return int(jax.lax.axis_size(axis_name))
-    raise TypeError(
-        "num_shards is required on JAX versions without jax.lax.axis_size; "
-        "pass it explicitly or build via make_sharded_mp"
-    )
+    return int(jax.lax.axis_size(axis_name))
 
 
 def allgather_mp_local(

@@ -36,12 +36,6 @@ Three pieces:
   sweeping latency-relevant flags offline and committing the winners.
   The resolved flag set's hash folds into the fingerprint, so retuning
   invalidates exactly the entries whose flags changed.
-
-When the pinned JAX has no executable-serialization API
-(``runtime.compat.HAS_SERIALIZE_EXECUTABLE`` false), the executor falls
-back to pointing JAX's own on-disk compilation cache at the same root
-(``runtime.compat.enable_compilation_cache``): restarts then still skip
-XLA compilation, paying only the (much smaller) retrace cost.
 """
 from __future__ import annotations
 

@@ -60,10 +60,9 @@ recompiling ~10s of programs.  ``xla_flags=`` (a ``serve.aot.
 XlaFlagConfig``, normally the checked-in autotuner table) supplies
 per-(model, bucket) XLA ``compiler_options`` applied at program build;
 the resolved set folds into the fingerprint so retuned flags
-self-invalidate exactly the entries they affect.  When the pinned JAX
-cannot serialize executables, the cache directory instead hosts JAX's
-own compilation cache (``runtime.compat.enable_compilation_cache``) —
-restarts then skip XLA compilation but still pay the retrace.
+self-invalidate exactly the entries they affect.  A flag set the backend
+rejects is an error: the program is never quietly compiled with other
+options than the ones asked for.
 
 **Telemetry.**  The executor accepts ``tracer=`` / ``metrics=`` sinks
 (``repro.obs``; the scheduler attaches its own via
@@ -79,7 +78,6 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import warnings
 from collections import OrderedDict
 from typing import Callable, Dict, Optional, Sequence, Set, Tuple
 
@@ -251,15 +249,9 @@ class Executor:
         if rules is None and mesh is not None:
             rules = RT.gnn_rules(mesh)
         self.rules = rules
-        # persistent AOT compile cache + per-program XLA flag table; when
-        # the pinned JAX cannot serialize executables the cache root hosts
-        # JAX's own compilation cache instead (compile skipped on restart,
-        # retrace still paid) — feature-detected, never an error
+        # persistent AOT compile cache + per-program XLA flag table
         self.aot = aot_cache
         self.xla_flags = xla_flags
-        self._aot_serialize = aot_cache is not None and RT.HAS_SERIALIZE_EXECUTABLE
-        if aot_cache is not None and not self._aot_serialize:
-            RT.enable_compilation_cache(aot_cache.root)
         self._env_fp_base: Optional[dict] = None  # lazy (touches devices)
         self._flags_cache: Dict[tuple, Dict[str, object]] = {}
         self.tenants: Dict[str, Tenant] = {}
@@ -394,10 +386,7 @@ class Executor:
 
     def _compiler_options(self, tenant: Tenant, bucket_key: tuple) -> dict:
         """The XLA compiler options for one (model, bucket) program,
-        resolved once and memoized — also the mutation point when a flag
-        set turns out invalid for this backend (we fall back to defaults
-        *and* remember that, so the store-side fingerprint matches what
-        was actually compiled)."""
+        resolved once and memoized."""
         if self.xla_flags is None:
             return {}
         key = (model_label(tenant.cfg), bucket_key)
@@ -502,21 +491,12 @@ class Executor:
                  p: PreparedBatch, flags: dict) -> Callable:
         """Fresh trace + lower + XLA compile of one signature's program,
         with the resolved XLA compiler options applied.  A flag set the
-        backend rejects falls back to a default compile — and the
-        resolved-flags memo is amended so the AOT write-back fingerprint
-        matches what was actually built."""
+        backend rejects raises: serving on other options than the table
+        names would hide what runs on the device."""
         lowered = cb.fn.lower(tenant.params, p.graph, p.eigvec, p.layout)
         cb.lowered_count += 1
         if flags:
-            try:
-                return lowered.compile(compiler_options=dict(flags))
-            except Exception as err:  # noqa: BLE001 - backend rejected a flag
-                key = (model_label(tenant.cfg), p.bucket_key)
-                self._flags_cache[key] = {}
-                warnings.warn(
-                    f"XLA flag set for {key} rejected by the backend "
-                    f"({err}); compiled with default options", stacklevel=2
-                )
+            return lowered.compile(compiler_options=dict(flags))
         return lowered.compile()
 
     def _executable(self, cb: _CompiledBucket, sig: tuple, tenant: Tenant,
@@ -526,7 +506,7 @@ class Executor:
         fresh compile with write-back otherwise."""
         flags = self._compiler_options(tenant, p.bucket_key)
         exe = None
-        if self._aot_serialize:
+        if self.aot is not None:
             key = (repr(tenant.program_key), p.bucket_key, p.num_graphs, sig)
             exe = self.aot.load(key, self._fingerprint(flags))
             if self._mi is not None:
@@ -537,11 +517,8 @@ class Executor:
                                   result=self.aot.last_result or "hit")
         if exe is None:
             exe = self._compile(cb, tenant, p, flags)
-            if self._aot_serialize:
-                # store under the *effective* flags (compile may have
-                # fallen back to defaults and amended the memo)
-                fp = self._fingerprint(self._compiler_options(tenant, p.bucket_key))
-                self.aot.store(key, fp, exe)
+            if self.aot is not None:
+                self.aot.store(key, self._fingerprint(flags), exe)
         return exe
 
     def _warm(self, cb: _CompiledBucket, sig: tuple, tenant: Tenant,

@@ -140,6 +140,20 @@ def test_mp_layer_spec_requires_layout(rng):
         mp.mp_layer(g, kw["msrc"], spec=spec, operands=kw)
 
 
+def test_compiled_dispatch_over_vmem_budget_raises(monkeypatch):
+    """On the chip, a resident footprint over the VMEM budget is an
+    error naming the bytes — never a quiet switch to the reference."""
+    monkeypatch.setattr(kops, "_on_tpu", lambda: True)
+    rows = kops._FUSED_VMEM_BUDGET // (100 * 4) + 1
+    msrc = np.zeros((rows, 100), np.float32)
+    n, e = rows, 8
+    spec = mp.MPSpec(phi="copy", ops=("sum",), gamma="gcn")
+    with pytest.raises(ValueError, match=f"{msrc.size * 4} resident bytes"):
+        kops.fused_mp(spec, np.zeros(e, np.int32), np.zeros(e, np.int32),
+                      np.zeros(n, np.float32), np.ones(n, bool), msrc, msrc,
+                      nop=np.zeros((n, 1), np.float32))
+
+
 def test_int8_row_eps_constants_pinned():
     """The kernel re-implements qconfig's dynamic recipe; the epsilon in
     `rs = max(rowmax|x|, eps) / 127` must stay one constant in all three

@@ -1,7 +1,6 @@
-"""repro.runtime.compat must behave identically whether or not the host
-JAX exposes the new mesh APIs (``get_abstract_mesh`` / ``set_mesh`` /
-``AxisType`` / public ``jax.shard_map``).  Both detection branches are
-exercised by monkeypatching the module-level feature flags."""
+"""repro.runtime.compat: the mesh facade over ``jax.set_mesh`` /
+``get_abstract_mesh`` / ``jax.shard_map``, the placement of JAX's
+persistent compilation cache, and the launchers' platform check."""
 import types
 
 import jax
@@ -13,16 +12,7 @@ from repro.runtime import compat
 from repro.runtime import partitioning as PT
 
 
-def test_make_mesh_with_and_without_axis_types(monkeypatch):
-    m = compat.make_mesh((1,), ("data",))
-    assert dict(m.shape) == {"data": 1}
-    monkeypatch.setattr(compat, "HAS_AXIS_TYPES", False)
-    m2 = compat.make_mesh((1,), ("data",))
-    assert dict(m2.shape) == {"data": 1}
-
-
-def test_get_active_mesh_absent_api_uses_use_mesh_context(monkeypatch):
-    monkeypatch.setattr(compat, "HAS_GET_ABSTRACT_MESH", False)
+def test_use_mesh_installs_and_restores_the_active_mesh():
     assert compat.get_active_mesh() is None
     mesh = compat.make_mesh((1,), ("data",))
     with compat.use_mesh(mesh):
@@ -33,43 +23,11 @@ def test_get_active_mesh_absent_api_uses_use_mesh_context(monkeypatch):
 
 def test_get_active_mesh_present_api_wins(monkeypatch):
     fake = types.SimpleNamespace(empty=False, size=4, shape={"data": 4})
-    monkeypatch.setattr(compat, "HAS_GET_ABSTRACT_MESH", True)
-    monkeypatch.setattr(
-        jax.sharding, "get_abstract_mesh", lambda: fake, raising=False
-    )
+    monkeypatch.setattr(jax.sharding, "get_abstract_mesh", lambda: fake)
     assert compat.get_active_mesh() is fake
 
 
-def test_get_active_mesh_present_but_empty_falls_through(monkeypatch):
-    empty = types.SimpleNamespace(empty=True, size=0, shape={})
-    monkeypatch.setattr(compat, "HAS_GET_ABSTRACT_MESH", True)
-    monkeypatch.setattr(
-        jax.sharding, "get_abstract_mesh", lambda: empty, raising=False
-    )
-    assert compat.get_active_mesh() is None
-    mesh = compat.make_mesh((1,), ("data",))
-    with compat.use_mesh(mesh):
-        got = compat.get_active_mesh()
-        assert got is not None and dict(got.shape) == {"data": 1}
-
-
-def test_shard_map_new_api_kwarg_rename(monkeypatch):
-    captured = {}
-
-    def fake_shard_map(f, mesh=None, in_specs=None, out_specs=None,
-                       check_vma=True):
-        captured["check_vma"] = check_vma
-        return f
-
-    monkeypatch.setattr(jax, "shard_map", fake_shard_map, raising=False)
-    monkeypatch.setattr(compat, "HAS_JAX_SHARD_MAP", True)
-    fn = compat.shard_map(lambda x: x, mesh=None, in_specs=(), out_specs=())
-    assert callable(fn)
-    assert captured == {"check_vma": False}
-
-
-def test_shard_map_executes_without_new_api(monkeypatch):
-    monkeypatch.setattr(compat, "HAS_JAX_SHARD_MAP", False)
+def test_shard_map_executes():
     mesh = compat.make_mesh((1,), ("d",))
     fn = compat.shard_map(
         lambda x: x * 2.0, mesh=mesh,
@@ -103,3 +61,48 @@ def test_deprecation_shims_are_gone():
                  "repro.launch.mesh"):
         with pytest.raises(ModuleNotFoundError):
             importlib.import_module(name)
+
+
+def test_compilation_cache_honours_the_environment(monkeypatch, tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, the helper sets no directory
+    of its own; unset, the cache lands at the fixed in-checkout path."""
+    from pathlib import Path
+
+    before = (jax.config.jax_compilation_cache_dir,
+              jax.config.jax_persistent_cache_min_compile_time_secs,
+              jax.config.jax_persistent_cache_min_entry_size_bytes)
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert compat.configure_compilation_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir is None
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        want = Path(__file__).resolve().parent.parent / ".jax_cache"
+        assert compat.default_compilation_cache_dir() == str(want)
+        assert compat.configure_compilation_cache() == str(want)
+        assert jax.config.jax_compilation_cache_dir == str(want)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before[0])
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          before[1])
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes",
+                          before[2])
+
+
+def test_require_tpu_refuses_an_unasked_cpu(monkeypatch):
+    """JAX on the CPU is accepted only when JAX_PLATFORMS asked for it;
+    a fall-back the user did not ask for stops the launcher."""
+    import pytest
+
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert compat.require_tpu() == "cpu"
+    monkeypatch.delenv("JAX_PLATFORMS")
+    with pytest.raises(SystemExit, match="no TPU found"):
+        compat.require_tpu()
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+    assert compat.require_tpu() == "cpu"
+    fake = types.SimpleNamespace(platform="tpu")
+    monkeypatch.setattr(jax, "devices", lambda *a: [fake])
+    monkeypatch.delenv("JAX_PLATFORMS")
+    assert compat.require_tpu() == "tpu"

@@ -44,8 +44,8 @@ serialization is single-path**.  Any reference to
 jax alias) outside ``serve/aot.py`` and ``serve/executor.py`` fails —
 a module that serializes executables is a module that can quietly grow a
 second persistence format with its own (unfingerprinted) invalidation
-story.  The real calls live behind ``runtime/compat.py``'s
-feature-detection; the serve/obs walk keeps everyone else out.
+story.  The real calls live behind ``runtime/compat.py``; the
+serve/obs walk keeps everyone else out.
 
 Since the pipelined execution mode landed, a third rule rides the same
 walk: **threading is single-path too**.  Any import of ``threading`` /
