@@ -27,6 +27,7 @@ import numpy as np
 
 from repro.core import graph as G
 from repro.core import layout as LY
+from repro.obs.trace import annotate
 
 # a raw host graph: (senders, receivers, node_feat[, edge_feat])
 RawGraph = tuple
@@ -134,6 +135,8 @@ def pack_prepared(
     sorts; the paper's convert-once-at-ingest, §3.4).  Returns
     ``(prepared, meta)`` — ``meta`` is the exact unpack bookkeeping.
 
+    The layout plan is built under the ``repro.layout`` profiler span.
+
     ``stage=True`` additionally ``jax.device_put``s the prepared pytree —
     the pipelined prepare worker uses this so the H2D copy for flush k+1
     happens while the device runs flush k, off the dispatch critical
@@ -148,7 +151,10 @@ def pack_prepared(
     eig = None
     if eigvecs is not None:
         eig = jnp.asarray(pack_eigvecs(eigvecs, meta), jnp.float32)
-    layout = pack_layout(packed) if with_layout else None
+    layout = None
+    if with_layout:
+        with annotate("layout", rung=budget.g_pad // 2):
+            layout = pack_layout(packed)
     prep = X.prepared(
         packed, eig, layout,
         ("packed", budget.n_pad, budget.e_pad, budget.g_pad), budget.g_pad,

@@ -93,7 +93,7 @@ CATALOG: Dict[str, Tuple[str, str, Tuple[str, ...]]] = {
         "counter", "Seconds of timed device execution", ()),
     "serve_d2h_seconds_total": (
         "counter", "Seconds spent in device-to-host output transfer "
-        "(the unpack_d2h span at result harvest)", ()),
+        "(the d2h span at result harvest)", ()),
     "serve_eigvec_cache_total": (
         "counter", "Host eigvec-LRU lookups, by result (hit|miss)",
         ("result",)),

@@ -24,23 +24,48 @@ Two recording styles, matching the two kinds of serving time:
   future relative to ``clock.now()``: record them with explicit
   boundaries via :meth:`Tracer.record`.
 
-The default sink everywhere is :data:`NULL_TRACER`, a shared no-op whose
-every method is a constant-return stub — no list append, no clock read,
-no attribute dict built (call sites guard attr construction on
-``tracer.enabled``).  Telemetry disabled is provably free: the scheduler
-emits the identical flush log and the executor builds the identical
-compile-key set with and without a live tracer attached
-(``tests/test_obs.py`` pins both).
+The default sink everywhere is :data:`NULL_TRACER`, which records
+nothing — no list append, no clock read; its ``span`` keeps only the
+profiler annotation below (call sites guard in-memory attr construction
+on ``tracer.enabled``).  Telemetry disabled is provably free: the
+scheduler emits the identical flush log and the executor builds the
+identical compile-key set with and without a live tracer attached, and
+the dark path's spans add no clock read (``tests/test_obs.py`` pins
+all three).
 
 Spans carry a ``track`` (one Perfetto thread row per track:
 ``scheduler`` / ``device`` / ``host`` / ``executor``) and sorted
 ``attrs`` tuples so serialization order never depends on dict insertion
 order.  Export lives in ``obs/export.py``.
+
+**Profiler sink.**  Every host-stage ``span`` — on a live ``Tracer`` and
+on ``NULL_TRACER`` alike — also opens a ``jax.profiler.TraceAnnotation``
+named ``repro.<name>`` with the span's attrs.  While a JAX profiler runs
+(``jax.profiler.trace`` / ``start_trace`` / ``start_server``) the stage
+lands in its ``.xplane.pb`` on the same clock as the device's ops; while
+none runs the annotation is inert (about a microsecond per span) and
+reads no clock of ours.  Code that holds no tracer (``core/batching.py``)
+and stages the in-memory timeline already models with ``record`` (a
+flush, the pipelined loop's pack) open the same annotation, without an
+in-memory span, through :func:`annotate`.  ``record`` and ``event`` are
+timeline-only: they write nothing to the profiler.
 """
 from __future__ import annotations
 
 import dataclasses
 from typing import List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
+
+PROFILER_PREFIX = "repro."  # host-stage spans in a profiler trace
+
+
+def annotate(name: str, **attrs) -> TraceAnnotation:
+    """The profiler half of a host-stage span alone: a
+    ``TraceAnnotation`` named ``repro.<name>`` carrying ``attrs`` (scalar
+    values).  For code that holds no tracer, and for stages the in-memory
+    timeline records through ``Tracer.record`` instead."""
+    return TraceAnnotation(PROFILER_PREFIX + name, **attrs)
 
 
 def _freeze_attrs(attrs: dict) -> Tuple[tuple, ...]:
@@ -69,9 +94,10 @@ class Span:
 
 class _LiveSpan:
     """Context manager recording one span on exit (exceptions included —
-    a failed stage still shows up in the trace, with its real duration)."""
+    a failed stage still shows up in the trace, with its real duration),
+    inside the stage's profiler annotation."""
 
-    __slots__ = ("_tracer", "_name", "_track", "_attrs", "_t0")
+    __slots__ = ("_tracer", "_name", "_track", "_attrs", "_t0", "_profiled")
 
     def __init__(self, tracer: "Tracer", name: str, track: str, attrs: dict):
         self._tracer = tracer
@@ -79,14 +105,23 @@ class _LiveSpan:
         self._track = track
         self._attrs = attrs
         self._t0 = 0.0
+        self._profiled = annotate(name, **attrs)
+
+    def note(self, **attrs) -> None:
+        """Add attrs known only inside the stage (a duration measured on
+        another clock) to the in-memory span; the profiler's copy keeps
+        the attrs it opened with."""
+        self._attrs.update(attrs)
 
     def __enter__(self):
+        self._profiled.__enter__()
         self._t0 = self._tracer.clock.now()
         return self
 
     def __exit__(self, *exc):
         self._tracer.record(self._name, self._t0, self._tracer.clock.now(),
                             track=self._track, **self._attrs)
+        self._profiled.__exit__(*exc)
         return False
 
 
@@ -103,7 +138,9 @@ class Tracer:
 
     def span(self, name: str, track: str = "host", **attrs) -> _LiveSpan:
         """Measure a host stage happening *now*:
-        ``with tracer.span("pack", tenant=..., bucket=...)``."""
+        ``with tracer.span("pack", tenant=..., bucket=...)`` — in memory
+        on this tracer's clock, and as ``repro.pack`` in a running
+        profiler."""
         return _LiveSpan(self, name, track, attrs)
 
     def record(self, name: str, t0_s: float, t1_s: float,
@@ -124,32 +161,17 @@ class Tracer:
         self.spans.clear()
 
 
-class _NullSpan:
-    """The shared no-op context manager ``NullTracer.span`` returns."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
-
-
 class NullTracer:
-    """The default sink: every method is a no-op, ``span`` hands back one
-    shared context manager, and nothing ever reads a clock.  Call sites
-    gate any attr-building work on ``tracer.enabled`` so the disabled
-    path allocates nothing."""
+    """The default sink: records nothing and never reads a clock.
+    ``span`` keeps only the profiler annotation (inert unless a profiler
+    runs); every other method is a no-op.  Call sites gate any in-memory
+    attr-building work on ``tracer.enabled``."""
 
     enabled = False
     spans: Tuple[()] = ()
 
-    def span(self, name: str, track: str = "host", **attrs) -> _NullSpan:
-        return _NULL_SPAN
+    def span(self, name: str, track: str = "host", **attrs) -> TraceAnnotation:
+        return annotate(name, **attrs)
 
     def record(self, name: str, t0_s: float, t1_s: float,
                track: str = "scheduler", **attrs) -> None:

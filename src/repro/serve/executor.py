@@ -528,16 +528,19 @@ class Executor:
         so neither compilation nor first-run warm can ever leak into a
         reported latency.  The two halves are accounted separately
         (``compile_s`` / ``warm_s``); returns total seconds spent (0.0
-        when already warm)."""
+        when already warm).  The build and the warm execution are the
+        ``compile`` span."""
         if sig in cb.warm:
             return 0.0
-        t0 = self.clock.now()
-        exe = self._executable(cb, sig, tenant, p)
-        cb.executables[sig] = exe
-        compile_dt = self.clock.now() - t0
-        t1 = self.clock.now()
-        jax.block_until_ready(exe(tenant.params, p.graph, p.eigvec, p.layout))
-        warm_dt = self.clock.now() - t1
+        with self.tracer.span("compile", track="executor", tenant=tenant.name,
+                              bucket=str(p.bucket_key)):
+            t0 = self.clock.now()
+            exe = self._executable(cb, sig, tenant, p)
+            cb.executables[sig] = exe
+            compile_dt = self.clock.now() - t0
+            t1 = self.clock.now()
+            jax.block_until_ready(exe(tenant.params, p.graph, p.eigvec, p.layout))
+            warm_dt = self.clock.now() - t1
         cb.warm.add(sig)
         cb.compile_s += compile_dt
         cb.warm_s += warm_dt
@@ -612,29 +615,29 @@ class Executor:
 
     def _harvest(self, out, tenant: Tenant, p: PreparedBatch,
                  t0: float) -> Tuple[np.ndarray, float]:
-        """Complete one dispatched execution: wait for the device, close
-        the timed region, then convert the outputs device-to-host under
-        the ``unpack_d2h`` accounting (the D2H copy used to hide outside
-        every measurement).  The extra clock reads are gated on a live
-        sink so the dark path stays free."""
-        out = jax.block_until_ready(out)
+        """Complete one dispatched execution: wait for the device (the
+        ``device_wait`` span), close the timed region, then convert the
+        outputs device-to-host (the ``d2h`` span; the D2H copy used to
+        hide outside every measurement).  The copy's seconds on this
+        executor's clock feed ``serve_d2h_seconds_total`` and the
+        in-memory ``d2h`` span's ``dur_s``; those clock reads are gated
+        on a live sink so the dark path stays free."""
+        tr, mi = self.tracer, self._mi
+        with tr.span("device_wait", track="executor", tenant=tenant.name):
+            out = jax.block_until_ready(out)
         dt = self.clock.now() - t0
-        accounted = self._mi is not None or self.tracer.enabled
-        if accounted:
-            t2 = self.clock.now()
-        host = np.asarray(out)
-        if accounted:
-            d2h = self.clock.now() - t2
-            if self._mi is not None:
-                self._mi.device_seconds.inc(dt)
-                self._mi.d2h_seconds.inc(d2h)
-            if self.tracer.enabled:
-                self.tracer.event("executor_run", track="executor",
-                                  tenant=tenant.name, bucket=str(p.bucket_key),
-                                  dur_s=dt)
-                self.tracer.event("unpack_d2h", track="executor",
-                                  tenant=tenant.name, bucket=str(p.bucket_key),
-                                  dur_s=d2h)
+        accounted = mi is not None or tr.enabled
+        with tr.span("d2h", track="executor", tenant=tenant.name) as copy:
+            if accounted:
+                t2 = self.clock.now()
+            host = np.asarray(out)
+            if accounted:
+                d2h = self.clock.now() - t2
+                if tr.enabled:
+                    copy.note(dur_s=d2h)
+        if mi is not None:
+            mi.device_seconds.inc(dt)
+            mi.d2h_seconds.inc(d2h)
         return host, dt
 
     def run_async(self, p: PreparedBatch,
@@ -645,17 +648,19 @@ class Executor:
         immediately — JAX's async dispatch keeps computing while the
         caller packs the next flush.  ``PendingRun.result()`` harvests
         the outputs and closes the timed region; the in-flight window is
-        the *caller's* responsibility (``serve/pipeline.py`` bounds it)."""
-        tenant = self.tenant(model)
-        cb = self._program(tenant, p.bucket_key, p.num_graphs)
-        sig = (tenant.params_sig,) + p.signature
-        with self._mesh_scope():
-            self._warm(cb, sig, tenant, p)
-            # dispatch through the signature's AOT executable (fresh or
-            # deserialized); cb.fn remains the lowering source/fallback
-            fn = cb.executables.get(sig, cb.fn)
-            t0 = self.clock.now()
-            out = fn(tenant.params, p.graph, p.eigvec, p.layout)
+        the *caller's* responsibility (``serve/pipeline.py`` bounds it).
+        Program lookup, warm check and enqueue are the ``dispatch`` span."""
+        with self.tracer.span("dispatch", track="executor", slots=p.num_graphs):
+            tenant = self.tenant(model)
+            cb = self._program(tenant, p.bucket_key, p.num_graphs)
+            sig = (tenant.params_sig,) + p.signature
+            with self._mesh_scope():
+                self._warm(cb, sig, tenant, p)
+                # dispatch through the signature's AOT executable (fresh or
+                # deserialized); cb.fn remains the lowering source/fallback
+                fn = cb.executables.get(sig, cb.fn)
+                t0 = self.clock.now()
+                out = fn(tenant.params, p.graph, p.eigvec, p.layout)
         return PendingRun(self, out, tenant, p, t0)
 
     def run(self, p: PreparedBatch,
@@ -721,7 +726,7 @@ class PendingRun:
     ``result()`` blocks until the device finishes, closes the timed
     region (``dt`` spans dispatch to completion-harvest on the
     executor's clock), converts the outputs to host memory under the
-    ``unpack_d2h`` accounting, and caches — a second call returns the
+    ``d2h`` span and accounting, and caches — a second call returns the
     same ``(outputs, seconds)`` without touching the device again.
     ``done`` flips once harvested (the in-flight bookkeeping hook)."""
 

@@ -88,6 +88,10 @@ sink is provably free — identical flush log, zero extra compile keys,
 zero clock reads (``tests/test_obs.py``).  ``StreamReport``'s
 aggregates are views over the same flush/shed event records the
 registry is fed from, so the two surfaces agree by construction.
+Whatever the sinks, the host stages of each call (``run``, ``flush``,
+``eigvec``, ``pack``, ``unpack`` here; ``layout`` in the packer;
+``dispatch``, ``compile``, ``device_wait``, ``d2h`` in the executor)
+open ``repro.*`` annotations that any running JAX profiler records.
 """
 from __future__ import annotations
 
@@ -105,7 +109,7 @@ from repro.core.batching import (
     unpack_outputs,
 )
 from repro.obs.metrics import MetricsRegistry, ServingInstruments
-from repro.obs.trace import NULL_TRACER, Tracer
+from repro.obs.trace import NULL_TRACER, Tracer, annotate
 from repro.serve.clock import Clock, VirtualClock
 from repro.serve.executor import Executor
 from repro.serve.pipeline import PipelineConfig, as_pipeline
@@ -701,8 +705,13 @@ class StreamScheduler:
         deterministic-simulation input).  ``models`` tags request i with
         a tenant name; ``priorities`` assigns its QoS class (default 0).
         Compute time is real measured engine time; compile/warm time is
-        excluded (tracked in the report).
+        excluded (tracked in the report).  The whole call is the
+        ``repro.run`` profiler span.
         """
+        with annotate("run", requests=len(graphs)):
+            return self._serve(graphs, qps, models, priorities, arrivals)
+
+    def _serve(self, graphs, qps, models, priorities, arrivals) -> StreamReport:
         if models is not None and len(models) != len(graphs):
             raise ValueError(
                 f"models ({len(models)}) must tag every graph ({len(graphs)})"
@@ -941,54 +950,66 @@ class StreamScheduler:
         raws = [r.graph for r in bucket.requests]
         if rung is None:
             rung = bucket.rung()
-        vecs = None
-        if self._needs_eigvec(model):
-            vecs = [
-                np.asarray(self.executor._eigvec(s, r, nf.shape[0], nf.shape[0]))
-                for s, r, nf, _ in (g[:4] for g in raws)
-            ]
         tr = self.tracer
-        with tr.span("pack", track="host", tenant=_tenant_label(model),
-                     graphs=len(raws), rung=rung.g_pad // 2):
-            prep, meta = pack_prepared(raws, rung, eigvecs=vecs,
-                                       with_layout=tenant.share_layout)
-        out, dt = self.executor.run(prep, model=model)
-        level = "graph" if tenant.cfg.task == "graph" else "node"
-        with tr.span("unpack", track="host", tenant=_tenant_label(model),
-                     graphs=len(raws)):
-            outs = unpack_outputs(out, meta, level=level)
+        label = _tenant_label(model)
+        with annotate("flush", graphs=len(raws), rung=rung.g_pad // 2):
+            vecs = None
+            if self._needs_eigvec(model):
+                with tr.span("eigvec", track="host", tenant=label,
+                             graphs=len(raws)):
+                    vecs = self._eigvecs(raws)
+            with tr.span("pack", track="host", tenant=label,
+                         graphs=len(raws), rung=rung.g_pad // 2):
+                prep, meta = pack_prepared(raws, rung, eigvecs=vecs,
+                                           with_layout=tenant.share_layout)
+            out, dt = self.executor.run(prep, model=model)
+            level = "graph" if tenant.cfg.task == "graph" else "node"
+            with tr.span("unpack", track="host", tenant=label,
+                         graphs=len(raws)):
+                outs = unpack_outputs(out, meta, level=level)
         return outs, dt
+
+    def _eigvecs(self, raws: Sequence[tuple]) -> List[np.ndarray]:
+        """Each graph's Laplacian eigenvector on the host (DGN's input)."""
+        return [
+            np.asarray(self.executor._eigvec(s, r, nf.shape[0], nf.shape[0]))
+            for s, r, nf, _ in (g[:4] for g in raws)
+        ]
 
     def _execute_pipelined(self, bucket: _OpenBucket, rung: BucketBudget,
                            measure_host: bool) -> Tuple[List[np.ndarray], float, float]:
         """Pack + run + unpack one bucket for the pipelined loop.
 
-        Unlike the serial ``_execute``, pack/unpack are *not* wrapped in
-        live tracer spans: the pipelined loop records them with modeled
-        timeline intervals instead (the pack span genuinely overlaps the
-        device span there).  With ``measure_host`` the real host-side
-        pack seconds (eigvec + ``pack_prepared``) are measured through
-        the executor's clock — the only real-time source the serving
-        stack may read — and returned for timeline folding; otherwise
-        the returned pack seconds are 0.0 and the caller's modeled
-        ``host_cost`` governs."""
+        Unlike the serial ``_execute``, pack/unpack are *not* live tracer
+        spans: the pipelined loop records them with modeled timeline
+        intervals instead (the pack span genuinely overlaps the device
+        span there).  The same ``repro.flush`` / ``eigvec`` / ``pack`` /
+        ``unpack`` profiler spans open here as in ``_execute``.  With
+        ``measure_host`` the real host-side pack seconds (eigvec +
+        ``pack_prepared``) are measured through the executor's clock —
+        the only real-time source the serving stack may read — and
+        returned for timeline folding; otherwise the returned pack
+        seconds are 0.0 and the caller's modeled ``host_cost`` governs."""
         model = bucket.model
         tenant = self.executor.tenant(model)
         raws = [r.graph for r in bucket.requests]
-        t_pack0 = self.executor.clock.now() if measure_host else 0.0
-        vecs = None
-        if self._needs_eigvec(model):
-            vecs = [
-                np.asarray(self.executor._eigvec(s, r, nf.shape[0], nf.shape[0]))
-                for s, r, nf, _ in (g[:4] for g in raws)
-            ]
-        prep, meta = pack_prepared(raws, rung, eigvecs=vecs,
-                                   with_layout=tenant.share_layout)
-        pack_wall_s = (self.executor.clock.now() - t_pack0
-                       if measure_host else 0.0)
-        out, dt = self.executor.run(prep, model=model)
-        level = "graph" if tenant.cfg.task == "graph" else "node"
-        outs = unpack_outputs(out, meta, level=level)
+        label = _tenant_label(model)
+        with annotate("flush", graphs=len(raws), rung=rung.g_pad // 2):
+            t_pack0 = self.executor.clock.now() if measure_host else 0.0
+            vecs = None
+            if self._needs_eigvec(model):
+                with annotate("eigvec", tenant=label, graphs=len(raws)):
+                    vecs = self._eigvecs(raws)
+            with annotate("pack", tenant=label, graphs=len(raws),
+                          rung=rung.g_pad // 2):
+                prep, meta = pack_prepared(raws, rung, eigvecs=vecs,
+                                           with_layout=tenant.share_layout)
+            pack_wall_s = (self.executor.clock.now() - t_pack0
+                           if measure_host else 0.0)
+            out, dt = self.executor.run(prep, model=model)
+            level = "graph" if tenant.cfg.task == "graph" else "node"
+            with annotate("unpack", tenant=label, graphs=len(raws)):
+                outs = unpack_outputs(out, meta, level=level)
         return outs, dt, pack_wall_s
 
     def _run_pipelined(self, requests: List[Request], clock: Clock,

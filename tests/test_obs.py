@@ -7,14 +7,19 @@ arrival trace + service times into the ``scripted_executor`` fake on a
 float — assertions are equalities, never tolerances.  Timestamps are
 binary fractions so the expected sums are exact in float64.
 """
+import contextlib
+import glob
 import json
+import os
 
 import numpy as np
 import pytest
 
 from conftest import scripted_executor
-from repro.obs import MetricsRegistry, Tracer, export
+from repro.obs import MetricsRegistry, NullTracer, Tracer, export
+from repro.obs import trace as trace_mod
 from repro.obs.metrics import ServingInstruments, default_registry
+from repro.serve import scheduler as sched_mod
 from repro.serve.clock import VirtualClock
 from repro.serve.scheduler import StreamScheduler
 
@@ -190,9 +195,40 @@ def test_trace_json_is_bitwise_identical_across_runs():
 SLOW_SLO = 0.125  # 1/8: generous, so the free-path scenario serves all
 
 
-def test_disabled_telemetry_is_provably_free():
+class CountingClock(VirtualClock):
+    """A VirtualClock that counts its reads."""
+
+    __slots__ = ("reads",)
+
+    def __init__(self):
+        super().__init__()
+        self.reads = 0
+
+    def now(self) -> float:
+        self.reads += 1
+        return super().now()
+
+
+class RecordingAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: notes each name."""
+
+    opened: list = []
+
+    def __init__(self, name, **attrs):
+        self.opened.append(name)
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+def test_disabled_telemetry_is_provably_free(monkeypatch):
     """No tracer/registry attached: identical flush log, latencies, and
-    executor call sequence — the no-op sink changes nothing."""
+    executor call sequence — the no-op sink changes nothing.  Its spans
+    still open their profiler annotations, and read no clock: a run with
+    every span taken out reads the run's clock exactly as often."""
     ex_on = scripted_executor(service_s=SVC)
     ex_off = scripted_executor(service_s=SVC)
     graphs = [graph(seed=i) for i in range(6)]
@@ -201,12 +237,30 @@ def test_disabled_telemetry_is_provably_free():
     rep_on = StreamScheduler(ex_on, tracer=Tracer(VirtualClock()),
                              metrics=MetricsRegistry(), **kw).run(
         graphs, arrivals=arrivals)
-    rep_off = StreamScheduler(ex_off, **kw).run(graphs, arrivals=arrivals)
+    monkeypatch.setattr(trace_mod, "TraceAnnotation", RecordingAnnotation)
+    monkeypatch.setattr(RecordingAnnotation, "opened", [])
+    clock_off = CountingClock()
+    rep_off = StreamScheduler(ex_off, clock=clock_off, **kw).run(
+        graphs, arrivals=arrivals)
 
     assert rep_on.flush_log == rep_off.flush_log  # frozen dataclasses: exact
     assert rep_on.shed == rep_off.shed
     np.testing.assert_array_equal(rep_on.latencies_s, rep_off.latencies_s)
     assert ex_on.run_log == ex_off.run_log
+
+    opened = RecordingAnnotation.opened
+    assert opened.count("repro.run") == 1
+    for stage in ("repro.flush", "repro.pack", "repro.unpack"):
+        assert opened.count(stage) == len(rep_off.flush_log)
+    bare = lambda *a, **k: contextlib.nullcontext()  # noqa: E731
+    monkeypatch.setattr(NullTracer, "span", bare)
+    monkeypatch.setattr(sched_mod, "annotate", bare)
+    clock_bare = CountingClock()
+    rep_bare = StreamScheduler(scripted_executor(service_s=SVC),
+                               clock=clock_bare, **kw).run(
+        graphs, arrivals=arrivals)
+    assert rep_bare.flush_log == rep_off.flush_log
+    assert clock_off.reads == clock_bare.reads > 0
 
 
 def test_disabled_telemetry_adds_zero_compile_keys():
@@ -245,7 +299,71 @@ def test_disabled_telemetry_adds_zero_compile_keys():
     assert reg.get("serve_device_seconds_total").value() == rep.compute_s
     assert spans_by_name(tracer, "program_build")
     assert spans_by_name(tracer, "warm")
-    assert len(spans_by_name(tracer, "executor_run")) == len(rep.flush_log)
+    assert len(spans_by_name(tracer, "device_wait")) == len(rep.flush_log)
+
+
+# ------------------------------------------------------ profiler stage spans
+
+
+def profiler_spans(trace_dir):
+    """``[name, start_ns, end_ns]`` of every ``repro.*`` host event in the
+    one ``.xplane.pb`` a ``jax.profiler.trace(trace_dir)`` wrote, outer
+    spans before the spans they enclose."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                     "*.xplane.pb"))
+    spans = [[e.name, int(e.start_ns), int(e.end_ns)]
+             for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name.startswith(trace_mod.PROFILER_PREFIX)]
+    return sorted(spans, key=lambda s: (s[1], -s[2]))
+
+
+@pytest.mark.parametrize("model", ["gin", "dgn"])
+def test_stage_spans_reach_the_profiler_trace(model, tmp_path):
+    """A served run under ``jax.profiler.trace`` leaves one ``repro.flush``
+    per flush, each holding its stages in serving order, all inside the
+    call's ``repro.run``; the eigenvector only for DGN, and no compile
+    once the ladder is warm."""
+    import jax
+
+    from repro.gnn import init
+    from repro.gnn.models import paper_config
+    from repro.serve.gnn_engine import GNNEngine
+
+    cfg = paper_config(model, num_layers=2, hidden=16,
+                       **({"head_hidden": (8,)} if model == "dgn" else {}))
+    eng = GNNEngine(cfg, init(jax.random.PRNGKey(0), cfg))
+    sched = StreamScheduler(eng, capacity=2, max_wait_s=MW, with_eigvec="auto")
+    graphs = [graph(seed=i, feat=9, e=16) for i in range(5)]
+    arrivals = [0.0, A1, 2 * A1, 3 * A1, MW + A1]
+    sched.run(graphs, arrivals=arrivals)  # warms every rung it reaches
+    with jax.profiler.trace(str(tmp_path)):
+        rep = sched.run(graphs, arrivals=arrivals)
+    spans = profiler_spans(str(tmp_path))
+
+    (run,) = [s for s in spans if s[0] == "repro.run"]
+    flushes = [s for s in spans if s[0] == "repro.flush"]
+    assert len(flushes) == len(rep.flush_log) == 2
+    stages = (["repro.eigvec"] if model == "dgn" else []) + [
+        "repro.pack", "repro.layout", "repro.dispatch", "repro.device_wait",
+        "repro.d2h", "repro.unpack"]
+    for _, lo, hi in flushes:
+        assert run[1] <= lo <= hi <= run[2]
+        inside = [s for s in spans if lo <= s[1] and s[2] <= hi
+                  and s[0] != "repro.flush"]
+        assert [s[0] for s in inside] == stages
+        by = {s[0]: s for s in inside}
+        pack, layout = by["repro.pack"], by["repro.layout"]
+        assert pack[1] <= layout[1] <= layout[2] <= pack[2]
+        ends = [by[n][2] for n in stages if n != "repro.layout"]
+        starts = [by[n][1] for n in stages if n != "repro.layout"]
+        assert all(e <= s for e, s in zip(ends, starts[1:]))
+    names = {s[0] for s in spans}
+    assert ("repro.eigvec" in names) == (model == "dgn")
+    assert "repro.compile" not in names
 
 
 # ------------------------------------------------------------ kernel census

@@ -459,9 +459,9 @@ def test_eigvec_lru_evicts_least_recent(monkeypatch):
 
 
 def test_d2h_span_and_counter(rng):
-    """Every harvested run converts outputs under the traced
-    ``unpack_d2h`` span, and the seconds land in the
-    ``serve_d2h_seconds_total`` counter."""
+    """Every harvested run waits under a ``device_wait`` span and
+    converts outputs under a ``d2h`` span, and the copy's seconds land in
+    the ``serve_d2h_seconds_total`` counter."""
     cfg = _reduced_config("gin")
     tr = Tracer(RealClock())
     reg = MetricsRegistry()
@@ -469,8 +469,8 @@ def test_d2h_span_and_counter(rng):
     eng.executor.attach_telemetry(tracer=tr, metrics=reg)
     gs = graphs(4, seed=41)
     eng.infer_stream(gs)
-    d2h = [s for s in tr.spans if s.name == "unpack_d2h"]
-    runs = [s for s in tr.spans if s.name == "executor_run"]
+    d2h = [s for s in tr.spans if s.name == "d2h"]
+    runs = [s for s in tr.spans if s.name == "device_wait"]
     assert len(d2h) == len(runs) == len(gs)
     assert all(dict(s.attrs)["dur_s"] >= 0.0 for s in d2h)
     text = export.prometheus_text(reg)
