@@ -16,13 +16,15 @@ node-offset slicing (node-level), never by masking heuristics.
 Everything here is host-side (numpy) construction — the packed ``Graph``
 enters the jit boundary exactly like a single padded graph does, so the
 engine's compiled buckets are reused across packed batches.
+``pack_prepared`` builds the whole pack-time payload on the host and
+crosses to the device with one ``jax.device_put``.
 """
 from __future__ import annotations
 
 import dataclasses
 from typing import List, Optional, Sequence, Tuple
 
-import jax.numpy as jnp
+import jax
 import numpy as np
 
 from repro.core import graph as G
@@ -84,7 +86,8 @@ def pack_graphs(graphs: Sequence[RawGraph], budget: BucketBudget) -> Tuple[G.Gra
 
     Node ids are shifted per graph; padding edges point at the final padded
     node, which belongs to no real graph, so they never contaminate real
-    aggregates (same invariant as single-graph padding).
+    aggregates (same invariant as single-graph padding).  The ``Graph``'s
+    leaves are numpy arrays (:func:`repro.core.graph.host_batch_graphs`).
     """
     if not graphs:
         raise ValueError("pack_graphs needs at least one graph")
@@ -97,7 +100,7 @@ def pack_graphs(graphs: Sequence[RawGraph], budget: BucketBudget) -> Tuple[G.Gra
             f"exceeds budget {budget}"
         )
     gs = [(g[0], g[1], g[2], g[3] if len(g) > 3 else None) for g in graphs]
-    packed = G.batch_graphs(gs, n_pad=budget.n_pad, e_pad=budget.e_pad)
+    packed = G.host_batch_graphs(gs, n_pad=budget.n_pad, e_pad=budget.e_pad)
     meta = PackMeta(
         budget=budget,
         node_counts=tuple(n for n, _ in sizes),
@@ -112,9 +115,10 @@ def pack_layout(packed: G.Graph) -> LY.GraphLayout:
     Host-side ``np.argsort(kind="stable")`` over the same masked keys the
     device path uses, so the plan is bit-identical to one built on device
     — but the compiled forward program that receives it contains **zero**
-    sort ops (the paper's convert-once-at-ingest, §3.4).  The scheduler
-    calls this right after :func:`pack_graphs` and hands the plan through
-    ``GNNEngine.infer_packed`` alongside the batch.
+    sort ops (the paper's convert-once-at-ingest, §3.4).  Built from the
+    host arrays of :func:`pack_graphs` with numpy leaves
+    (:func:`repro.core.layout.host_layout`); :func:`pack_prepared` puts
+    it on the device together with the batch.
     """
     return LY.host_layout(packed)
 
@@ -124,7 +128,6 @@ def pack_prepared(
     budget: BucketBudget,
     eigvecs: Optional[Sequence[np.ndarray]] = None,
     with_layout: bool = True,
-    stage: bool = False,
 ):
     """Pack raw graphs and emit the whole pack-time payload as one
     ``serve.executor.PreparedBatch``: padded graph, packed eigenvectors,
@@ -135,22 +138,15 @@ def pack_prepared(
     sorts; the paper's convert-once-at-ingest, §3.4).  Returns
     ``(prepared, meta)`` — ``meta`` is the exact unpack bookkeeping.
 
-    The layout plan is built under the ``repro.layout`` profiler span.
-
-    ``stage=True`` additionally ``jax.device_put``s the prepared pytree —
-    the pipelined prepare worker uses this so the H2D copy for flush k+1
-    happens while the device runs flush k, off the dispatch critical
-    path (``PreparedBatch`` is a registered pytree; its static metadata
-    rides along untouched).
+    Every leaf is built in numpy, the warm signature included; the batch
+    then crosses to the default device in one ``jax.device_put``, with no
+    device round trip in between.  The layout plan is built under the
+    ``repro.layout`` profiler span.
     """
-    import jax  # deferred with the executor import below
-
     from repro.serve import executor as X  # deferred: serve imports core
 
     packed, meta = pack_graphs(graphs, budget)
-    eig = None
-    if eigvecs is not None:
-        eig = jnp.asarray(pack_eigvecs(eigvecs, meta), jnp.float32)
+    eig = None if eigvecs is None else pack_eigvecs(eigvecs, meta)
     layout = None
     if with_layout:
         with annotate("layout", rung=budget.g_pad // 2):
@@ -159,9 +155,7 @@ def pack_prepared(
         packed, eig, layout,
         ("packed", budget.n_pad, budget.e_pad, budget.g_pad), budget.g_pad,
     )
-    if stage:
-        prep = jax.device_put(prep)
-    return prep, meta
+    return jax.device_put(prep), meta
 
 
 def pack_eigvecs(eigvecs: Sequence[np.ndarray], meta: PackMeta) -> np.ndarray:

@@ -168,13 +168,15 @@ def from_numpy(
     )
 
 
-def batch_graphs(graphs: list, n_pad: int, e_pad: int) -> Graph:
-    """Pack a list of small host graphs into one padded batch (jraph-style).
+def host_batch_graphs(graphs: list, n_pad: int, e_pad: int) -> Graph:
+    """Pack a list of small host graphs into one padded batch (jraph-style)
+    whose leaves are numpy arrays: nothing touches the device.
 
     Node ids are shifted per graph; padding edges point at the final padded
     node which belongs to no real graph.  This is the TPU-efficient serving
     mode; batch-size-1 streaming (the paper's real-time mode) is the special
-    case of a single graph per batch.
+    case of a single graph per batch.  ``core.batching`` builds the whole
+    pack-time payload on these arrays and crosses to the device once.
     """
     nfs, eis, efs, gids = [], [], [], []
     offset = 0
@@ -200,11 +202,17 @@ def batch_graphs(graphs: list, n_pad: int, e_pad: int) -> Graph:
     gid = np.full((n_pad,), len(graphs), np.int32)  # padding -> out-of-range id
     gid[:n] = np.concatenate(gids)
     return Graph(
-        node_feat=jnp.asarray(nf),
-        edge_index=jnp.asarray(ei),
-        edge_feat=jnp.asarray(ef),
-        node_mask=jnp.asarray(np.arange(n_pad) < n),
-        edge_mask=jnp.asarray(np.arange(e_pad) < e),
-        graph_id=jnp.asarray(gid),
-        n_graph=jnp.asarray(len(graphs), np.int32),
+        node_feat=nf,
+        edge_index=ei,
+        edge_feat=ef,
+        node_mask=np.arange(n_pad) < n,
+        edge_mask=np.arange(e_pad) < e,
+        graph_id=gid,
+        n_graph=np.asarray(len(graphs), np.int32),
     )
+
+
+def batch_graphs(graphs: list, n_pad: int, e_pad: int) -> Graph:
+    """:func:`host_batch_graphs` followed by one ``jax.device_put``: the
+    padded batch with device leaves (same shapes, dtypes and bits)."""
+    return jax.device_put(host_batch_graphs(graphs, n_pad, e_pad))

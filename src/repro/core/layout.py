@@ -38,7 +38,8 @@ on the graph (and, for DGN, its eigenvector input), not on the layer:
 on-device edge sort for the message-passing path (enforced by
 ``tools/check_no_raw_sort.py``); ``host_layout`` is its bit-identical
 numpy twin used by ``core.batching`` so a packed batch's plan is emitted
-at pack time and the compiled forward program contains **zero** sorts.
+at pack time, from host arrays, and the compiled forward program
+contains **zero** sorts.
 """
 from __future__ import annotations
 
@@ -117,24 +118,31 @@ def host_layout(graph: G.Graph) -> GraphLayout:
     identical permutation to the device path, so a host-built plan drops
     into the compiled program without changing a single bit of output —
     while removing the last on-device sort from the packed forward.
+
+    Built from host arrays and returned with numpy leaves: given the
+    numpy ``Graph`` of ``core.batching`` it touches no device, and the
+    plan crosses with the rest of its batch.  A device ``Graph`` still
+    works; its ``edge_index`` and ``edge_mask`` are read back once.
     """
     n = graph.num_nodes
     edge_mask = np.asarray(graph.edge_mask)
-    dst = np.where(edge_mask, np.asarray(graph.dst), n).astype(np.int32)
-    src = np.asarray(graph.src).astype(np.int32)
+    edge_index = np.asarray(graph.edge_index)
+    src = edge_index[0].astype(np.int32)
+    real_dst = edge_index[1]
+    dst = np.where(edge_mask, real_dst, n).astype(np.int32)
     perm = np.argsort(dst, kind="stable").astype(np.int32)
     ids_sorted = dst[perm]
     offsets = np.searchsorted(
         ids_sorted, np.arange(n + 1, dtype=np.int32), side="left"
     ).astype(np.int32)
     deg = np.zeros((n,), np.int32)
-    np.add.at(deg, np.asarray(graph.dst)[edge_mask], 1)
+    np.add.at(deg, real_dst[edge_mask], 1)
     return GraphLayout(
-        perm=jnp.asarray(perm),
-        ids_sorted=jnp.asarray(ids_sorted),
-        offsets=jnp.asarray(offsets),
-        src_sorted=jnp.asarray(src[perm]),
-        in_degree=jnp.asarray(deg),
+        perm=perm,
+        ids_sorted=ids_sorted,
+        offsets=offsets,
+        src_sorted=src[perm],
+        in_degree=deg,
     )
 
 

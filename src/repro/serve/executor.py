@@ -594,14 +594,17 @@ class Executor:
         (zero on-device sorts in the flushed program); when absent and the
         tenant shares layouts, the host plan is built here — the plan
         always travels with its batch, never a sort inside the program.
+        Host leaves (``core.batching.pack_graphs``' numpy graph, the host
+        plan) cross to the device in one put, before any timed region.
         """
         if eigvec is not None:
             eigvec = jnp.asarray(eigvec, jnp.float32)
         if layout is None and self.tenant(model).share_layout:
             layout = B.pack_layout(packed)
-        return prepared(packed, eigvec, layout,
-                        ("packed", budget.n_pad, budget.e_pad, budget.g_pad),
-                        budget.g_pad)
+        return jax.device_put(prepared(
+            packed, eigvec, layout,
+            ("packed", budget.n_pad, budget.e_pad, budget.g_pad), budget.g_pad,
+        ))
 
     def has_program(self, bucket_key: tuple, num_graphs: int,
                     model: Optional[str] = None) -> bool:

@@ -1,0 +1,203 @@
+"""The packed flush is built on the host and crosses to the device once.
+
+``core.batching.pack_prepared`` assembles the padded graph, the packed
+eigenvector, the layout plan and the warm signature from numpy arrays,
+then makes one ``jax.device_put``.  These tests hold it to that:
+
+  * no implicit host-to-device transfer happens while packing (under
+    ``jax.transfer_guard_host_to_device("disallow")``), exactly one
+    ``jax.device_put`` is made per call, and ``host_layout`` only ever
+    sees numpy arrays;
+  * the ``PreparedBatch`` is the one a construction through the device
+    gives (``batch_graphs``, then the plan read back from its device graph;
+    ``Executor.prepare_packed`` of a device graph):
+    every leaf bitwise equal, with equal shapes, dtypes, weak types and
+    warm signature, and the plan equal to the on-device sort's;
+  * served through the scheduler after ``prewarm_ladders``, no program is
+    lowered again, and all six models serve the same bits from either
+    construction.
+"""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from repro.core import layout as LY
+from repro.core.batching import BucketBudget, pack_eigvecs, pack_graphs, pack_prepared
+from repro.core.graph import batch_graphs
+from repro.gnn import init
+from repro.gnn.models import paper_config
+from repro.serve import executor as X
+from repro.serve.executor import DEFAULT_BUCKETS
+from repro.serve.gnn_engine import GNNEngine
+from repro.serve.scheduler import StreamScheduler
+
+KEY = jax.random.PRNGKey(0)
+# every rung of the default ladder: each base bucket at StreamScheduler's
+# default capacity of 4 (rung multiples 1, 2, 3, 4)
+RUNGS = [
+    BucketBudget(n_pad=k * nb, e_pad=k * eb, g_pad=2 * k)
+    for nb, eb in DEFAULT_BUCKETS
+    for k in (1, 2, 3, 4)
+]
+
+
+def _molecules(rng, budget, count):
+    """``count`` seeded molecules that together fit ``budget``, sizes drawn
+    anew per call so the padding left over varies (padding fuzz)."""
+    n_cap = max(1, budget.n_pad // count)
+    e_cap = max(1, budget.e_pad // count)
+    out = []
+    for _ in range(count):
+        n = int(rng.integers(1, n_cap + 1))
+        e = int(rng.integers(1, e_cap + 1))
+        out.append((
+            rng.integers(0, n, e).astype(np.int32),
+            rng.integers(0, n, e).astype(np.int32),
+            rng.normal(size=(n, 9)).astype(np.float32),
+            rng.normal(size=(e, 3)).astype(np.float32),
+        ))
+    return out
+
+
+def _eigvecs(rng, graphs):
+    return [rng.normal(size=(g[2].shape[0],)).astype(np.float32) for g in graphs]
+
+
+def _device_graph_prepared(graphs, budget, vecs, share_layout=True):
+    """The construction with a device round trip: the batch put on the
+    device leaf by leaf, the eigenvector through ``jnp.asarray``, the plan
+    read back from the device graph and put there again."""
+    g = batch_graphs([tuple(x[:4]) for x in graphs],
+                     n_pad=budget.n_pad, e_pad=budget.e_pad)
+    _, meta = pack_graphs(graphs, budget)
+    eig = None if vecs is None else jnp.asarray(pack_eigvecs(vecs, meta),
+                                                jnp.float32)
+    layout = (jax.tree.map(jnp.asarray, LY.host_layout(g))
+              if share_layout else None)
+    return X.prepared(g, eig, layout,
+                      ("packed", budget.n_pad, budget.e_pad, budget.g_pad),
+                      budget.g_pad)
+
+
+def _leaf_facts(tree):
+    leaves, treedef = jax.tree.flatten(tree)
+    return treedef, [(x.shape, str(x.dtype), jax.typeof(x).weak_type)
+                     for x in leaves]
+
+
+def _assert_same_batch(got, want, msg):
+    assert got.bucket_key == want.bucket_key, msg
+    assert got.num_graphs == want.num_graphs, msg
+    assert got.signature == want.signature, msg
+    assert _leaf_facts(got) == _leaf_facts(want), msg
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert isinstance(a, jax.Array), msg
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=msg)
+
+
+# ------------------------------------------------------------ one crossing
+
+
+@pytest.mark.parametrize("with_eig", [False, True], ids=["plain", "eigvec"])
+@pytest.mark.parametrize("fill", ["one", "several", "full"])
+def test_pack_prepared_crosses_to_the_device_once(fill, with_eig, rng,
+                                                  monkeypatch):
+    budget = BucketBudget(n_pad=128, e_pad=384, g_pad=8)
+    count = {"one": 1, "several": 3, "full": budget.g_pad}[fill]
+    graphs = _molecules(rng, budget, count)
+    vecs = _eigvecs(rng, graphs) if with_eig else None
+
+    puts, layout_inputs = [], []
+    real_put, real_layout = jax.device_put, LY.host_layout
+
+    def counting_put(*args, **kwargs):
+        puts.append(args)
+        return real_put(*args, **kwargs)
+
+    def recording_layout(graph):
+        layout_inputs.append(jax.tree.leaves(graph))
+        return real_layout(graph)
+
+    monkeypatch.setattr(jax, "device_put", counting_put)
+    monkeypatch.setattr(LY, "host_layout", recording_layout)
+    with jax.transfer_guard_host_to_device("disallow"):
+        prep, meta = pack_prepared(graphs, budget, eigvecs=vecs)
+
+    assert len(puts) == 1
+    assert len(layout_inputs) == 1
+    assert all(type(x) is np.ndarray for x in layout_inputs[0])
+    assert meta.num_graphs == count
+    assert (prep.eigvec is not None) == with_eig
+    assert all(isinstance(x, jax.Array) for x in jax.tree.leaves(prep))
+
+
+# ------------------------------------------------------- nothing else moved
+
+
+@pytest.mark.parametrize("model", ["gin", "dgn"])
+@pytest.mark.parametrize("rung", RUNGS, ids=lambda b: f"{b.n_pad}x{b.e_pad}x{b.g_pad}")
+def test_pack_prepared_equals_device_graph_construction(model, rung, rng):
+    for count in sorted({1, max(1, rung.g_pad // 2), rung.g_pad}):
+        graphs = _molecules(rng, rung, count)
+        vecs = _eigvecs(rng, graphs) if model == "dgn" else None
+        got, _ = pack_prepared(graphs, rung, eigvecs=vecs)
+        want = _device_graph_prepared(graphs, rung, vecs)
+        msg = f"{model} {rung} graphs={count}"
+        _assert_same_batch(got, want, msg)
+        device_plan = LY.build_layout(want.graph)
+        for a, b in zip(jax.tree.leaves(got.layout),
+                        jax.tree.leaves(device_plan)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                          err_msg=msg + " (device sort)")
+
+
+def test_pack_prepared_without_layout_equals_device_graph_construction(rng):
+    budget = BucketBudget(n_pad=64, e_pad=192, g_pad=4)
+    graphs = _molecules(rng, budget, 3)
+    got, _ = pack_prepared(graphs, budget, with_layout=False)
+    assert got.layout is None
+    want = _device_graph_prepared(graphs, budget, None, share_layout=False)
+    _assert_same_batch(got, want, "no plan")
+
+
+# --------------------------------------------------- served, no new program
+
+
+def _reduced_config(name):
+    model, vn = ("gin", True) if name == "gin_vn" else (name, False)
+    kw = dict(num_layers=2, virtual_node=vn)
+    kw.update(dict(heads=2, head_features=8) if model == "gat" else dict(hidden=16))
+    return paper_config(model, **kw)
+
+
+@pytest.mark.parametrize("name", ["gcn", "gin", "gin_vn", "gat", "pna", "dgn"])
+def test_served_bits_and_programs_unchanged(name, rng):
+    cfg = _reduced_config(name)
+    eng = GNNEngine(cfg, init(KEY, cfg), buckets=((32, 96),))
+    sched = StreamScheduler(eng, capacity=2, max_wait_s=0.001,
+                            with_eigvec="auto")
+    budget = BucketBudget(n_pad=32, e_pad=96, g_pad=2)
+    graphs = _molecules(rng, budget, 2) + _molecules(rng, budget, 2)
+    sched.prewarm_ladders(graphs)
+    ex = eng.executor
+    lowered = ex.lowered_count
+    assert lowered > 0
+
+    rep = sched.run(graphs)
+    assert len(rep.outputs) == len(graphs)
+    assert ex.lowered_count == lowered, "a flush lowered a new program"
+
+    vecs = sched._eigvecs(graphs[:2]) if cfg.model == "dgn" else None
+    new, _ = pack_prepared(graphs[:2], budget, eigvecs=vecs,
+                           with_layout=eng.share_layout)
+    _, meta = pack_graphs(graphs[:2], budget)
+    old = ex.prepare_packed(
+        batch_graphs(graphs[:2], n_pad=budget.n_pad, e_pad=budget.e_pad),
+        budget, eigvec=None if vecs is None else pack_eigvecs(vecs, meta),
+        model=eng.name)
+    _assert_same_batch(new, old, name)
+    out_new, _ = ex.run(new, model=eng.name)
+    out_old, _ = ex.run(old, model=eng.name)
+    np.testing.assert_array_equal(out_new, out_old, err_msg=name)
+    assert ex.lowered_count == lowered, "the two constructions differ in program"

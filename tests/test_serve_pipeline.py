@@ -395,17 +395,23 @@ def test_pipelined_stream_validation_and_staging(rng):
 
 
 def test_pack_prepared_stage_is_bitwise_transparent(rng):
-    from repro.core.batching import BucketBudget, pack_prepared
+    """``pack_prepared`` stages the batch on the device in one put; served,
+    it gives the bits of the same batch left on the host."""
+    from repro.core.batching import BucketBudget, pack_graphs, pack_layout, pack_prepared
+    from repro.serve.executor import prepared
 
     cfg = _reduced_config("gin")
     eng = GNNEngine(cfg, init(KEY, cfg), buckets=((16, 32),))
     gs = graphs(4, seed=17)
     budget = BucketBudget(64, 128, 8)
-    prep, _ = pack_prepared(gs, budget, with_layout=eng.share_layout)
-    staged, _ = pack_prepared(gs, budget, with_layout=eng.share_layout,
-                              stage=True)
-    assert staged.bucket_key == prep.bucket_key
-    out_a, _ = eng.executor.run(prep, model=eng.name)
+    staged, _ = pack_prepared(gs, budget)
+    packed, _ = pack_graphs(gs, budget)
+    host = prepared(packed, None, pack_layout(packed), staged.bucket_key,
+                    budget.g_pad)
+    assert all(isinstance(x, jax.Array) for x in jax.tree.leaves(staged))
+    assert all(isinstance(x, np.ndarray) for x in jax.tree.leaves(host))
+    assert staged.signature == host.signature
+    out_a, _ = eng.executor.run(host, model=eng.name)
     out_b, _ = eng.executor.run(staged, model=eng.name)
     np.testing.assert_array_equal(np.asarray(out_a), np.asarray(out_b))
 
