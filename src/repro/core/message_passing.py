@@ -332,6 +332,50 @@ def dgn_aggregate(
 
 
 # ---------------------------------------------------------------------------
+# GatedGCN aggregation and random-walk structural encoding (GraphGPS)
+# ---------------------------------------------------------------------------
+
+
+def gated_aggregate(
+    graph: Graph,
+    gates: jax.Array,
+    values: jax.Array,
+    layout: Optional[LY.GraphLayout] = None,
+    eps: float = 1e-6,
+) -> jax.Array:
+    """GatedGCN's A(.): sum_j sigma_ij * v_j / (sum_j sigma_ij + eps).
+
+    ``gates`` (sigma) and ``values`` (the gathered source values) are
+    (E, F) in COO order; both sums consume one permuted stream of the
+    shared plan, as GIN's sum does (zero sorts).  Returns (N, F)."""
+    f = values.shape[-1]
+    sums = gather_scatter(graph, jnp.concatenate([gates * values, gates], -1),
+                          ops=("sum",), layout=layout)
+    return sums[:, :f] / (sums[:, f:] + eps)
+
+
+def random_walk_se(graph: Graph, steps: int) -> jax.Array:
+    """RWSE: (N, steps) landing probabilities diag(P^k), k = 1..steps, of
+    the random walk P = D^-1 A (A[i, j] counts edges i -> j, D the
+    out-degree; a node without out-edges has a zero row), as GraphGPS's
+    ``get_rw_landing_probs``.  P is dense over the graph's node rows: no
+    edge crosses graphs, so P is block-diagonal and each graph of a packed
+    batch gets its own values.  Padding edges add nothing.
+
+    The powers double in batches (P^(m+1..2m) = P^(1..m) P^m), so the
+    ``steps - 1`` products run as about log2(steps) batched ones."""
+    n = graph.num_nodes
+    a = jnp.zeros((n, n), jnp.float32).at[graph.src, graph.dst].add(
+        graph.edge_mask.astype(jnp.float32))
+    p = a / jnp.maximum(a.sum(axis=1, keepdims=True), 1.0)
+    powers = p[None]  # (m, N, N): P^1 .. P^m
+    while powers.shape[0] < steps:
+        m = powers.shape[0]
+        powers = jnp.concatenate([powers, powers[:steps - m] @ powers[-1]])
+    return jnp.diagonal(powers, axis1=1, axis2=2).T
+
+
+# ---------------------------------------------------------------------------
 # Global graph pooling (graph-level tasks, paper §3.3)
 # ---------------------------------------------------------------------------
 

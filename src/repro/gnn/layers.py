@@ -109,3 +109,54 @@ def batch_norm_init(dim: int) -> dict:
 
 def batch_norm_apply(p: dict, x: jax.Array) -> jax.Array:
     return x * p["scale"] + p["shift"]
+
+
+def batch_norm_stats_init(rng, dim: int, var_scale: float = 1.0) -> dict:
+    """Inference batch norm from running statistics (GraphGPS's
+    ``BatchNorm1d`` at eval): seeded random running mean and variance,
+    scale and shift, so that no normalization is the identity by
+    accident.  The variance is drawn within a factor 4/3 of
+    ``var_scale``, the variance the norm's input has in a network whose
+    layers see unit-scale input, as a trained model's running statistics
+    would be."""
+    km, kv, kg, kb = jax.random.split(rng, 4)
+    return {"mean": 0.1 * jax.random.normal(km, (dim,)),
+            "var": var_scale * jax.random.uniform(kv, (dim,), minval=0.75,
+                                                  maxval=4.0 / 3.0),
+            "gamma": 1.0 + 0.1 * jax.random.normal(kg, (dim,)),
+            "beta": 0.1 * jax.random.normal(kb, (dim,))}
+
+
+BN_EPS = 1e-5  # PyTorch BatchNorm1d's default, as GraphGPS runs it
+
+
+def batch_norm_fold(p: dict, eps: float = BN_EPS) -> jax.Array:
+    """``(x - mean) / sqrt(var + eps) * gamma + beta`` as one per-feature
+    affine folded from the running statistics: a (2, dim) array of the
+    scale and the shift, applied by :func:`affine_apply`."""
+    scale = p["gamma"] * jax.lax.rsqrt(p["var"] + eps)
+    return jnp.stack([scale, p["beta"] - p["mean"] * scale])
+
+
+def affine_apply(st: jax.Array, x: jax.Array) -> jax.Array:
+    """``x * scale + shift`` for a folded ``(2, dim)`` affine."""
+    return x * st[0] + st[1]
+
+
+def segment_attention(qkv: jax.Array, g, heads: int,
+                      mode: str = "auto") -> jax.Array:
+    """Multi-head self-attention of each graph of a (packed) ``Graph``
+    over its own nodes, from the projected ``qkv`` (N, 3W: queries, keys
+    and values, heads split contiguously); returns the heads side by side
+    (N, W), before the output projection.  The attention is
+    ``kernels/flash_attention`` with the graph ids as segment ids; padded
+    node rows attend nowhere and output 0.  Its Pallas op is named
+    ``gps_attention`` in the program and the device trace."""
+    n, w = qkv.shape[0], qkv.shape[1] // 3
+    # one head-major copy of all three: (3, heads, N, features)
+    t = qkv.reshape(n, 3, heads, w // heads).transpose(1, 2, 0, 3)
+    q, k, v = t[0:1], t[1:2], t[2:3]
+    seg = jnp.where(g.node_mask, g.graph_id, -1).astype(jnp.int32)[None]
+    out = ops.flash_attention(q, k, v, causal=False, mode=mode,
+                              segment_ids=seg, name="gps_attention")
+    return out[0].transpose(1, 0, 2).reshape(n, w)

@@ -1,5 +1,5 @@
-"""The six representative GNN models (paper Table 2 / §4), on the generic
-message-passing core.
+"""The six representative GNN models (paper Table 2 / §4) and GraphGPS, on
+the generic message-passing core.
 
 Every model is expressed through the same (phi, A, gamma) triple the paper
 uses, so the engine (serve/gnn_engine.py) runs all of them unchanged —
@@ -9,6 +9,12 @@ the 'generic' claim.  Configurations default to the paper's §5.1 settings:
   PNA                : 4 layers, dim 80,  mean pool, MLP head (40, 20, 1)
   DGN                : 4 layers, dim 100, mean pool, MLP head (50, 25, 1)
   GAT                : 5 layers, 4 heads x 16 features, mean pool, linear head
+
+and GraphGPS (Rampasek et al., arXiv:2205.12454) at its ogbg-molpcba
+widths: 5 GPS layers at 384 (a GatedGCN local MPNN with an edge state
+carried across layers, 4-head attention within each graph, FFN
+384-768-384, inference BatchNorm), RWSE of 16 random-walk steps computed
+in the program, mean pool, a linear head to 128 tasks.
 """
 from __future__ import annotations
 
@@ -23,18 +29,26 @@ from repro.core import layout as LY
 from repro.core import message_passing as mp
 from repro.gnn import layers as L
 from repro.kernels import ops as kops
+from repro.kernels.flash_attention import segment_pairs
+
+# Models whose layers attend among the nodes of each graph of a packed
+# batch (GPS's global attention, masked by graph).
+GRAPH_ATTENTION = frozenset({"gps"})
 
 
 @dataclasses.dataclass(frozen=True)
 class GNNConfig:
-    model: str = "gin"  # gcn | gin | gat | pna | dgn
+    model: str = "gin"  # gcn | gin | gat | pna | dgn | gps
     num_layers: int = 5
     hidden: int = 100
     feat_dim: int = 9  # OGB mol atom features (as floats)
     edge_dim: int = 3  # OGB mol bond features
     out_dim: int = 1
-    heads: int = 4  # GAT
-    head_features: int = 16  # GAT per-head features
+    heads: int = 4  # GAT / GPS attention heads
+    head_features: int = 16  # GAT / GPS per-head features
+    pe_steps: int = 16  # GPS: random-walk steps of the RWSE
+    pe_dim: int = 20  # GPS: RWSE projection, concatenated to the atom embedding
+    ffn_hidden: int = 768  # GPS: feed-forward inner width
     avg_degree: float = 2.2  # PNA scaler constant (MolHIV train stat)
     task: str = "graph"  # graph | node
     virtual_node: bool = False
@@ -44,6 +58,21 @@ class GNNConfig:
     @property
     def width(self) -> int:
         return self.heads * self.head_features if self.model == "gat" else self.hidden
+
+    @property
+    def graph_attention(self) -> bool:
+        return self.model in GRAPH_ATTENTION
+
+
+def attention_pairs(cfg: GNNConfig, node_counts, rows: int) -> Optional[tuple]:
+    """A packed batch's (real, computed) query-key pairs of the per-graph
+    attention: within its graphs (sum of n^2) and in the tiles the
+    segment-id kernel runs over its ``rows`` node rows
+    (``kernels.flash_attention.segment_pairs``); None for a model without
+    per-graph attention."""
+    if cfg.model not in GRAPH_ATTENTION:
+        return None
+    return segment_pairs(node_counts, rows)
 
 
 def paper_config(model: str, virtual_node: bool = False, **kw) -> GNNConfig:
@@ -56,6 +85,9 @@ def paper_config(model: str, virtual_node: bool = False, **kw) -> GNNConfig:
         base.update(num_layers=4, hidden=80, head_hidden=(40, 20))
     elif model == "dgn":
         base.update(num_layers=4, hidden=100, head_hidden=(50, 25))
+    elif model == "gps":  # GraphGPS, ogbg-molpcba (arXiv:2205.12454)
+        base.update(num_layers=5, hidden=384, heads=4, head_features=96,
+                    out_dim=128, pe_steps=16, pe_dim=20, ffn_hidden=768)
     else:
         raise ValueError(model)
     base.update(kw)
@@ -68,6 +100,8 @@ def paper_config(model: str, virtual_node: bool = False, **kw) -> GNNConfig:
 
 
 def init(rng: jax.Array, cfg: GNNConfig) -> dict:
+    if cfg.model == "gps":
+        return _gps_init(rng, cfg)
     keys = iter(jax.random.split(rng, 4 + 4 * cfg.num_layers))
     w = cfg.width
     params: dict = {"encoder": L.linear_init(next(keys), cfg.feat_dim, w), "layers": []}
@@ -106,6 +140,87 @@ def init(rng: jax.Array, cfg: GNNConfig) -> dict:
     head_sizes = (w,) + tuple(cfg.head_hidden) + (cfg.out_dim,)
     params["head"] = L.mlp_init(next(keys), head_sizes)
     return params
+
+
+# the centre of each GPS BatchNorm's seeded running variance: the variance
+# its input has when the layers see unit-scale input (glorot weights,
+# molecule graphs; norm_ff's chosen so that the residual stream keeps unit
+# scale through the layers, as a trained model's statistics keep it — with
+# unit variances every layer would grow it about threefold)
+GPS_BN_VAR = {"pe_norm": 0.03, "bn_x": 1.7, "bn_e": 2.1, "norm_local": 2.2,
+              "norm_attn": 1.2, "norm_ff": 4.5}
+
+
+def _gps_init(rng: jax.Array, cfg: GNNConfig) -> dict:
+    """GraphGPS parameters.  Every BatchNorm holds seeded random running
+    statistics (``L.batch_norm_stats_init``), so no normalization is the
+    identity by accident; the attention's q/k/v projections are one fused
+    linear (PyTorch's ``in_proj``), heads split contiguously."""
+    w, f = cfg.hidden, cfg.ffn_hidden
+    keys = iter(jax.random.split(rng, 6 + 13 * cfg.num_layers))
+
+    def encoder(k, d_in, d_out):  # unit-scale embeddings of unit inputs
+        return {"w": jax.random.normal(k, (d_in, d_out)) / jnp.sqrt(d_in),
+                "b": jnp.zeros((d_out,))}
+
+    params: dict = {
+        "atom": encoder(next(keys), cfg.feat_dim, w - cfg.pe_dim),
+        "pe_norm": L.batch_norm_stats_init(next(keys), cfg.pe_steps,
+                                           GPS_BN_VAR["pe_norm"]),
+        "pe": encoder(next(keys), cfg.pe_steps, cfg.pe_dim),
+        "bond": encoder(next(keys), cfg.edge_dim, w),
+        "layers": [],
+    }
+    for _ in range(cfg.num_layers):
+        lp = {k: L.linear_init(next(keys), w, w) for k in "ABCDE"}
+        for k in ("bn_x", "bn_e", "norm_local", "norm_attn", "norm_ff"):
+            lp[k] = L.batch_norm_stats_init(next(keys), w, GPS_BN_VAR[k])
+        lp["attn"] = {"qkv": L.linear_init(next(keys), w, 3 * w),
+                      "out": L.linear_init(next(keys), w, w)}
+        lp["ff"] = L.mlp_init(next(keys), (w, f, w))
+        params["layers"].append(lp)
+    params["head"] = L.mlp_init(next(keys), (w,) + tuple(cfg.head_hidden)
+                                + (cfg.out_dim,))
+    return params
+
+
+GPS_NORMS = ("bn_x", "bn_e", "norm_local", "norm_attn", "norm_ff")
+
+
+def serving_params(params: dict, cfg: GNNConfig) -> dict:
+    """The tree a model serves from, made once by ``Executor.register``
+    (``apply`` makes it inside the program when handed ``init``'s tree).
+
+    GraphGPS serves from 20 arrays instead of 202, since the compiler
+    moves each array a program reads with copies of its own, which cost
+    device ops in every flush.  In each layer the five linears that read
+    the node state (A, B, D, E and the attention's q/k/v) become one
+    product, and the five inference BatchNorms fold into a (5, 2, hidden)
+    array of scales and shifts; then the layers stack along a leading
+    axis, which ``layer_params`` indexes.  Every other model serves the
+    tree it is given."""
+    if cfg.model != "gps" or isinstance(params["layers"], dict):
+        return params
+
+    def packed(lp):
+        proj = [lp[c] for c in "ABDE"] + [lp["attn"]["qkv"]]
+        return {"x_proj": {k: jnp.concatenate([p[k] for p in proj], axis=-1)
+                           for k in ("w", "b")},
+                "C": lp["C"], "out": lp["attn"]["out"], "ff": lp["ff"],
+                "norms": jnp.stack([L.batch_norm_fold(lp[k]) for k in GPS_NORMS])}
+
+    layers = [packed(lp) for lp in params["layers"]]
+    return {"atom": params["atom"], "pe": params["pe"], "bond": params["bond"],
+            "head": params["head"], "pe_norm": L.batch_norm_fold(params["pe_norm"]),
+            "layers": jax.tree.map(lambda *a: jnp.stack(a), *layers)}
+
+
+def layer_params(layers, li: int):
+    """Layer ``li``'s parameters from a list of layers or from layers
+    stacked along a leading axis (``serving_params``)."""
+    if isinstance(layers, dict):
+        return jax.tree.map(lambda a: a[li], layers)
+    return layers[li]
 
 
 # ---------------------------------------------------------------------------
@@ -321,8 +436,78 @@ def _dgn_layer(g: G.Graph, x, lp, cfg, extras):
     return mp.mp_layer(g, x, phi, gamma, aggregate=aggregate, layout=layout)
 
 
-_LAYERS = {"gcn": _gcn_layer, "gin": _gin_layer, "gat": _gat_layer,
-           "pna": _pna_layer, "dgn": _dgn_layer}
+def _gps_layer(g: G.Graph, x, e, lp, cfg, extras):
+    """One GraphGPS layer (arXiv:2205.12454 eq. 2-4, GraphGPS's
+    ``GPSLayer`` order), i the destination and j the source of edge j->i:
+
+      e^_ij = D x_i + E x_j + C e_ij,  sigma_ij = sigmoid(e^_ij)
+      x_M   = BN(x + relu(BN(A x_i + sum_j sigma_ij B x_j
+                                    / (sum_j sigma_ij + 1e-6))))
+      e'    = e + relu(BN(e^_ij))
+      x_T   = BN(x + MHA(x)), attention within each graph only
+      x'    = BN(s + W2 relu(W1 s)),  s = x_M + x_T
+
+    The gated sums ride the shared plan (``mp.gated_aggregate``); the
+    attention is ``kernels/flash_attention`` with the packed flush's graph
+    ids as segment ids.  ``lp`` is a layer of ``serving_params``: one
+    product gives A x, B x, D x, E x and q/k/v, and ``lp["norms"]`` holds
+    the five BatchNorms in the order of ``GPS_NORMS``.  The edge state
+    ``e`` stays in COO order."""
+    layout, mode, w = extras["layout"], cfg.kernel_mode, cfg.hidden
+    norms = lp["norms"]
+    h = L.linear_apply(lp["x_proj"], x, mode=mode)
+    ax, bx, dx, ex = (h[:, i * w:(i + 1) * w] for i in range(4))
+    e_hat = (jnp.take(dx, g.dst, axis=0) + jnp.take(ex, g.src, axis=0)
+             + L.linear_apply(lp["C"], e, mode=mode))
+    agg = mp.gated_aggregate(g, jax.nn.sigmoid(e_hat),
+                             jnp.take(bx, g.src, axis=0), layout=layout)
+    x_local = x + jax.nn.relu(L.affine_apply(norms[0], ax + agg))
+    e = e + jax.nn.relu(L.affine_apply(norms[1], e_hat))
+    x_local = L.affine_apply(norms[2], x_local)
+
+    heads = L.segment_attention(h[:, 4 * w:], g, cfg.heads, mode=mode)
+    attn = L.linear_apply(lp["out"], heads, mode=mode)
+    x_attn = L.affine_apply(norms[3], x + attn)
+
+    s = x_local + x_attn
+    out = L.affine_apply(norms[4], s + L.mlp_apply(lp["ff"], s, mode=mode))
+    return jnp.where(g.node_mask[:, None], out, 0.0), e
+
+
+def _node_layer(fn):
+    """A layer body of node state alone, lifted to the (x, e) state the
+    forward carries: the edge state passes through untouched."""
+
+    def layer(g, x, e, lp, cfg, extras):
+        return fn(g, x, lp, cfg, extras), e
+
+    return layer
+
+
+# every body maps the carried state (x, e) -> (x, e); ``e`` is the edge
+# state of models that update one (GPS), None for the rest
+_LAYERS = {"gcn": _node_layer(_gcn_layer), "gin": _node_layer(_gin_layer),
+           "gat": _node_layer(_gat_layer), "pna": _node_layer(_pna_layer),
+           "dgn": _node_layer(_dgn_layer), "gps": _gps_layer}
+
+
+def _encode(params: dict, g: G.Graph, cfg: GNNConfig):
+    """The input state (x, e): the node encoder's embedding and, for GPS,
+    ``[W_atom x || W_pe BN(RWSE)]`` with the bond embedding as the first
+    edge state.  The RWSE is computed here from the graph, in the program
+    (no request-time preprocessing on the host)."""
+    mode = cfg.kernel_mode
+    if cfg.model != "gps":
+        x = L.linear_apply(params["encoder"], g.node_feat, mode=mode)
+        return jnp.where(g.node_mask[:, None], x, 0.0), None
+    rw = mp.random_walk_se(g, cfg.pe_steps)
+    pe = L.linear_apply(
+        params["pe"], L.affine_apply(params["pe_norm"], rw),
+        mode=mode)
+    x = jnp.concatenate(
+        [L.linear_apply(params["atom"], g.node_feat, mode=mode), pe], axis=-1)
+    e = L.linear_apply(params["bond"], g.edge_feat, mode=mode)
+    return jnp.where(g.node_mask[:, None], x, 0.0), e
 
 
 # ---------------------------------------------------------------------------
@@ -365,6 +550,7 @@ def apply(
     parity oracle, exactly as the per-call-sort path is for layouts.
     """
     m = g.num_nodes if num_graphs is None else num_graphs
+    params = serving_params(params, cfg)
     layer_fn = _LAYERS[cfg.model]
     if share_layout:
         layout = LY.for_model(
@@ -373,8 +559,7 @@ def apply(
     else:
         layout = None
     extras = {"eigvec": eigvec, "layout": layout, "fused": fused}
-    x = L.linear_apply(params["encoder"], g.node_feat, mode=cfg.kernel_mode)
-    x = jnp.where(g.node_mask[:, None], x, 0.0)
+    x, e = _encode(params, g, cfg)
     vn = None  # (m, w) per-graph virtual-node state
     if cfg.virtual_node:
         vn = jnp.broadcast_to(params["vn_embed"], (m, x.shape[-1]))
@@ -384,7 +569,7 @@ def apply(
             # virtual node broadcasts its state to every node of its graph
             gid = jnp.clip(g.graph_id, 0, m - 1)
             x = x + jnp.take(vn, gid, axis=0) * g.node_mask[:, None]
-        x = layer_fn(g, x, params["layers"][li], cfg, extras)
+        x, e = layer_fn(g, x, e, layer_params(params["layers"], li), cfg, extras)
         if cfg.virtual_node and li < cfg.num_layers - 1:
             # vn_{l+1} = MLP(vn_l + sum-pool of that graph's nodes)
             pooled = mp.global_pool(g, x, op="sum", num_graphs=m)

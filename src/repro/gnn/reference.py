@@ -9,11 +9,14 @@ the code paths.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.graph import Graph
+from repro.gnn.layers import BN_EPS
 from repro.gnn.models import GNNConfig
 
 
@@ -37,8 +40,8 @@ def _mlp(ps, x, act="relu", final="none"):
     return x
 
 
-def _lin(p, x, act="none"):
-    y = x @ p["w"] + p["b"]
+def _lin(p, x, act="none", mm=jnp.matmul):
+    y = mm(x, p["w"]) + p["b"]
     if act == "relu":
         y = jnp.maximum(y, 0)
     elif act == "gelu":
@@ -58,7 +61,78 @@ def _masked_pool(g: Graph, x, op="mean"):
     return total / jnp.maximum(count, 1.0)
 
 
+def _bn(p, x, eps):
+    return (x - p["mean"]) / jnp.sqrt(p["var"] + eps) * p["gamma"] + p["beta"]
+
+
+def apply_gps_dense(params, g: Graph, cfg: GNNConfig, mm=jnp.matmul) -> jax.Array:
+    """GraphGPS (arXiv:2205.12454) written out plainly: per-edge gathers
+    and the gated sums as one-hot matrix products, attention as dense
+    (N, N) scores per head masked to each graph's real nodes, RWSE as
+    explicit powers of the dense random-walk matrix.  ``mm`` computes every
+    matrix product (QK^T and PV included), so a lower-precision product
+    reaches all of it.  Returns (N, out_dim): row k is graph k's output."""
+    n, eps = g.num_nodes, BN_EPS
+    lin = functools.partial(_lin, mm=mm)
+    nm = g.node_mask[:, None].astype(jnp.float32)
+    em = g.edge_mask.astype(jnp.float32)
+    ids = jnp.arange(n)[None, :]
+    to_src = (g.src[:, None] == ids).astype(jnp.float32)  # (E, N)
+    to_dst = (g.dst[:, None] == ids).astype(jnp.float32) * em[:, None]
+    # RWSE: P = D^-1 A over out-edges, diag(P^k) for k = 1..pe_steps
+    a = mm(to_src.T, to_dst)  # a[i, j] = number of real edges i -> j
+    p = a / jnp.maximum(a.sum(1, keepdims=True), 1.0)
+    walk, rw = p, [jnp.diagonal(p)]
+    for _ in range(cfg.pe_steps - 1):
+        walk = mm(walk, p)
+        rw.append(jnp.diagonal(walk))
+    pe = lin(params["pe"], _bn(params["pe_norm"], jnp.stack(rw, -1), eps))
+    x = jnp.concatenate([lin(params["atom"], g.node_feat), pe], -1) * nm
+    e = lin(params["bond"], g.edge_feat)
+    same = (g.graph_id[:, None] == g.graph_id[None, :]) & (nm > 0) & (nm.T > 0)
+    h, dh = cfg.heads, cfg.hidden // cfg.heads
+    for lp in params["layers"]:
+        # GatedGCN with its own residuals, edge state carried
+        x_dst, x_src = mm(to_dst, x), mm(to_src, x)
+        e_hat = (lin(lp["D"], x_dst) + lin(lp["E"], x_src)
+                 + lin(lp["C"], e))
+        sig = jax.nn.sigmoid(e_hat)
+        num = mm(to_dst.T, sig * lin(lp["B"], x_src))
+        den = mm(to_dst.T, sig)
+        x_m = lin(lp["A"], x) + num / (den + 1e-6)
+        x_m = x + jnp.maximum(_bn(lp["bn_x"], x_m, eps), 0.0)
+        e = e + jnp.maximum(_bn(lp["bn_e"], e_hat, eps), 0.0)
+        x_m = _bn(lp["norm_local"], x_m, eps)
+        # attention within each graph, one head at a time
+        qkv = lin(lp["attn"]["qkv"], x)
+        heads = []
+        for k in range(h):
+            q = qkv[:, k * dh:(k + 1) * dh]
+            kk = qkv[:, cfg.hidden + k * dh:cfg.hidden + (k + 1) * dh]
+            v = qkv[:, 2 * cfg.hidden + k * dh:2 * cfg.hidden + (k + 1) * dh]
+            s = jnp.where(same, mm(q, kk.T) / jnp.sqrt(float(dh)), -jnp.inf)
+            s = s - jnp.max(jnp.where(same, s, -1e30), axis=1, keepdims=True)
+            w = jnp.where(same, jnp.exp(s), 0.0)
+            w = w / jnp.maximum(w.sum(1, keepdims=True), 1e-30)
+            heads.append(mm(w, v))
+        attn = lin(lp["attn"]["out"], jnp.concatenate(heads, -1))
+        x_t = _bn(lp["norm_attn"], x + attn, eps)
+        s = x_m + x_t
+        ff = lin(lp["ff"][1], jnp.maximum(lin(lp["ff"][0], s), 0.0))
+        x = _bn(lp["norm_ff"], s + ff, eps) * nm
+    gid = jnp.where(g.node_mask, g.graph_id, n)
+    onehot = (gid[:, None] == ids).astype(jnp.float32)
+    pooled = mm(onehot.T, x) / jnp.maximum(onehot.sum(0)[:, None], 1.0)
+    for i, head in enumerate(params["head"]):
+        pooled = lin(head, pooled)
+        if i < len(params["head"]) - 1:
+            pooled = jnp.maximum(pooled, 0.0)
+    return pooled
+
+
 def apply_dense(params, g: Graph, cfg: GNNConfig, eigvec=None) -> jax.Array:
+    if cfg.model == "gps":
+        return apply_gps_dense(params, g, cfg)
     a = dense_adjacency(g)  # (N,N) in-edges: a[i, j] = j -> i
     nm = g.node_mask[:, None].astype(jnp.float32)
     x = _lin(params["encoder"], g.node_feat) * nm

@@ -335,13 +335,19 @@ def flash_attention(
     mode: str = "auto",
     block_q: int = 128,
     block_k: int = 128,
+    segment_ids: jax.Array | None = None,
+    name: str | None = None,
 ) -> jax.Array:
-    """Blockwise GQA attention."""
+    """Blockwise GQA attention; with ``segment_ids`` ((B, S), -1 for a
+    row that attends nowhere) a query sees only keys of its own segment.
+    ``name`` labels the kernel's op in the program and the device trace."""
     use_kernel, interpret = _resolve(mode)
     _record_dispatch("flash_attention", use_kernel, interpret)
     if not use_kernel:
-        return ref.flash_attention_ref(q, k, v, causal=causal, window=window)
+        return ref.flash_attention_ref(q, k, v, causal=causal, window=window,
+                                       segment_ids=segment_ids)
     return _flash_kernel(
         q, k, v, causal=causal, window=window,
         block_q=block_q, block_k=block_k, interpret=interpret,
+        segment_ids=segment_ids, name=name,
     )

@@ -253,12 +253,14 @@ def flash_attention_ref(
     causal: bool = True,
     window: int | None = None,
     scale: float | None = None,
+    segment_ids: jax.Array | None = None,
 ) -> jax.Array:
     """Full (quadratic) GQA attention oracle.
 
     q: (B, Hq, S, D); k/v: (B, Hkv, S, D) with Hq % Hkv == 0.
     window: sliding-window size (None = full); causal mask always applied
-    when ``causal``.
+    when ``causal``.  segment_ids: (B, S) int32 — a query sees only keys
+    of its own id, and a row with a negative id sees none and outputs 0.
     """
     b, hq, s, d = q.shape
     hkv = k.shape[1]
@@ -274,7 +276,12 @@ def flash_attention_ref(
         mask &= ki <= qi
     if window is not None:
         mask &= ki > qi - window
+    mask = jnp.broadcast_to(mask, (b, 1, s, s))
+    if segment_ids is not None:
+        seg = segment_ids[:, None, :, None]
+        mask = mask & (seg == segment_ids[:, None, None, :]) & (seg >= 0)
     logits = jnp.where(mask, logits, -1e30)
     p = jax.nn.softmax(logits, axis=-1)
+    p = jnp.where(mask, p, 0.0)  # a row with no key at all outputs 0
     out = jnp.einsum("bhqk,bhkd->bhqd", p, vq.astype(jnp.float32))
     return out.astype(q.dtype)

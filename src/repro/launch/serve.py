@@ -289,7 +289,8 @@ def main():
     t0 = time.perf_counter()  # cold-start epoch: launcher entry
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCHS)
-    ap.add_argument("--gnn", choices=("gcn", "gin", "gin_vn", "gat", "pna", "dgn"))
+    ap.add_argument("--gnn", choices=("gcn", "gin", "gin_vn", "gat", "pna", "dgn",
+                                      "gps"))
     ap.add_argument("--models",
                     help="multi-tenant GNN serving: comma-separated "
                          "model[:precision] specs (e.g. gcn:int8,gat:fp32) "

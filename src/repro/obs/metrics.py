@@ -97,6 +97,10 @@ CATALOG: Dict[str, Tuple[str, str, Tuple[str, ...]]] = {
     "serve_eigvec_cache_total": (
         "counter", "Host eigvec-LRU lookups, by result (hit|miss)",
         ("result",)),
+    "serve_attention_pairs_total": (
+        "counter", "Query-key pairs of the per-graph attention of each "
+        "flush: within its graphs (real, sum of n^2) and in the tiles the "
+        "attention kernel runs (computed)", ("kind",)),
     # ---- pipeline: dispatch-ahead execution
     "serve_inflight_depth": (
         "gauge", "Dispatched-but-unharvested flushes in the pipelined "
@@ -339,5 +343,6 @@ class ServingInstruments:
         self.device_seconds = registry.counter("serve_device_seconds_total")
         self.d2h_seconds = registry.counter("serve_d2h_seconds_total")
         self.eigvec_cache = registry.counter("serve_eigvec_cache_total")
+        self.attention_pairs = registry.counter("serve_attention_pairs_total")
         self.inflight_depth = registry.gauge("serve_inflight_depth")
         self.pack_ewma = registry.gauge("serve_pack_ewma_seconds")

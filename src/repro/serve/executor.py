@@ -292,7 +292,8 @@ class Executor:
     ) -> Tenant:
         """Admit a model into the shared machinery.  ``precision`` selects
         the serving arithmetic ("fp32", "int8", "int8-static", "fixed");
-        quantization happens once here and every mode then serves the
+        the model's serving tree (``models.serving_params``) and any
+        quantization are made once here and every mode then serves the
         transformed tree.  ``fused`` lowers eligible layers through the
         ``kernels.ops.fused_mp`` megakernel (requires a layout plan —
         layers without one, and opt-outs like GAT, keep the unfused path).
@@ -303,6 +304,7 @@ class Executor:
         params and warm state never cross tenants."""
         if name in self.tenants:
             raise ValueError(f"tenant {name!r} already registered")
+        params = M.serving_params(params, cfg)
         quant_report = None
         if precision != "fp32":
             from repro.quant import apply as QA

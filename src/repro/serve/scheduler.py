@@ -108,6 +108,7 @@ from repro.core.batching import (
     pack_prepared,
     unpack_outputs,
 )
+from repro.gnn.models import attention_pairs
 from repro.obs.metrics import MetricsRegistry, ServingInstruments
 from repro.obs.trace import NULL_TRACER, Tracer, annotate
 from repro.serve.clock import Clock, VirtualClock
@@ -952,7 +953,7 @@ class StreamScheduler:
             rung = bucket.rung()
         tr = self.tracer
         label = _tenant_label(model)
-        with annotate("flush", graphs=len(raws), rung=rung.g_pad // 2):
+        with annotate("flush", **self._flush_attrs(tenant, raws, rung)):
             vecs = None
             if self._needs_eigvec(model):
                 with tr.span("eigvec", track="host", tenant=label,
@@ -968,6 +969,23 @@ class StreamScheduler:
                          graphs=len(raws)):
                 outs = unpack_outputs(out, meta, level=level)
         return outs, dt
+
+    def _flush_attrs(self, tenant, raws: Sequence[tuple],
+                     rung: BucketBudget) -> dict:
+        """The ``repro.flush`` span's attributes: graphs and rung, and for a
+        model with per-graph attention (``gnn.models.attention_pairs``)
+        the flush's query-key pairs within its graphs and in the kernel's
+        tiles, which are also added to ``serve_attention_pairs_total``."""
+        attrs = {"graphs": len(raws), "rung": rung.g_pad // 2}
+        pairs = attention_pairs(tenant.cfg, [g[2].shape[0] for g in raws],
+                                rung.n_pad)
+        if pairs is not None:
+            real, computed = pairs
+            attrs.update(attn_pairs_real=real, attn_pairs_computed=computed)
+            if self._mi is not None:
+                self._mi.attention_pairs.inc(real, kind="real")
+                self._mi.attention_pairs.inc(computed, kind="computed")
+        return attrs
 
     def _eigvecs(self, raws: Sequence[tuple]) -> List[np.ndarray]:
         """Each graph's Laplacian eigenvector on the host (DGN's input)."""
@@ -994,7 +1012,7 @@ class StreamScheduler:
         tenant = self.executor.tenant(model)
         raws = [r.graph for r in bucket.requests]
         label = _tenant_label(model)
-        with annotate("flush", graphs=len(raws), rung=rung.g_pad // 2):
+        with annotate("flush", **self._flush_attrs(tenant, raws, rung)):
             t_pack0 = self.executor.clock.now() if measure_host else 0.0
             vecs = None
             if self._needs_eigvec(model):

@@ -168,9 +168,10 @@ def test_fused_mp_compiles_for_v5e(spec_of, gamma, precision):
 
 
 def _served_forward(monkeypatch, model, sharding, mesh=None,
-                    precision="fp32", fused=False):
+                    precision="fp32", fused=False, rows=N, edges=E):
     """Compile the whole jitted program ``Executor`` builds for ``model``
-    at the top packed rung, every input placed by ``sharding``.
+    at a packed rung (the top one unless ``rows``/``edges`` say), every
+    input placed by ``sharding``.
     ``kernels.ops`` picks the Pallas path from the backend, which is the
     CPU here, so the rehearsal steers it to the chip's choice."""
     from repro.configs.gengnn_models import get_gnn_config
@@ -187,7 +188,7 @@ def _served_forward(monkeypatch, model, sharding, mesh=None,
     graphs = [g[:4] for g in MoleculeStream(MOLHIV, seed=0).take(8)]
     eigvecs = ([np.zeros(g[2].shape[0], np.float32) for g in graphs]
                if cfg.model == "dgn" else None)
-    prep, _ = pack_prepared(graphs, BucketBudget(n_pad=N, e_pad=E, g_pad=8),
+    prep, _ = pack_prepared(graphs, BucketBudget(n_pad=rows, e_pad=edges, g_pad=8),
                             eigvecs=eigvecs)
     program = ex._program(tenant, prep.bucket_key, prep.num_graphs)
 
@@ -223,6 +224,39 @@ def test_served_forward_compiles_for_v5e(one_chip, no_persistent_cache,
     text = _served_forward(monkeypatch, model, one_chip,
                            precision=precision, fused=fused)
     assert "tpu_custom_call" in text
+
+
+def test_gps_served_forward_compiles_for_v5e(one_chip, no_persistent_cache,
+                                             monkeypatch):
+    """GraphGPS at its molpcba widths: the node MLPs, and the segment-id
+    attention kernel under its stable name, one per layer."""
+    text = _served_forward(monkeypatch, "gps", one_chip)
+    assert "tpu_custom_call" in text
+    assert text.count("gps_attention") >= 5
+
+
+# Device ops (and async copies, which the trace lists twice) one GPS flush
+# may run.  The profiler keeps a bounded number of device events: a 45 s
+# traced window of GPS screening holds ~10,000 flushes, and at ~1,030
+# events per flush (the 202 weight arrays each copied to on-chip memory)
+# the trace lost its first seconds.  The serving tree brings it to ~320.
+GPS_OPS_PER_FLUSH = 340
+
+
+def test_gps_served_forward_stays_within_its_device_op_budget(
+        one_chip, no_persistent_cache, monkeypatch):
+    """At a rung of the screening cell (256 rows), counted in the compiled
+    program's entry computation."""
+    import re
+
+    text = _served_forward(monkeypatch, "gps", one_chip, rows=256, edges=768)
+    entry = text[text.index("ENTRY"):]
+    entry = entry[:entry.index("\n}\n")]
+    kinds = re.findall(r" = .*? ([a-z][\w-]*)\(", entry)
+    ops = [k for k in kinds
+           if k not in ("parameter", "bitcast", "get-tuple-element", "constant")]
+    starts = [k for k in ops if k in ("copy-start", "slice-start")]
+    assert len(ops) + len(starts) <= GPS_OPS_PER_FLUSH
 
 
 @pytest.mark.parametrize("model,fused", [
