@@ -257,7 +257,7 @@ class Executor:
         self.tenants: Dict[str, Tenant] = {}
         self._compiled: Dict[tuple, _CompiledBucket] = {}
         # host eigvec memo: (edge bytes, n, n_pad) -> computed vector
-        self._eigvec_lru: "OrderedDict[tuple, jax.Array]" = OrderedDict()
+        self._eigvec_lru: "OrderedDict[tuple, np.ndarray]" = OrderedDict()
         # telemetry sinks: dark by default (the no-op tracer / no registry
         # costs nothing and adds no compile keys); the scheduler attaches
         # its own sinks here so compile/warm/device events share them
@@ -565,7 +565,8 @@ class Executor:
         s, r, nf, ef = raw[:4]
         nb, eb = self.bucket_for(nf.shape[0], len(s))
         g = G.from_numpy(s, r, nf, ef, n_pad=nb, e_pad=eb)
-        eig = self._eigvec(s, r, nf.shape[0], nb) if with_eigvec else None
+        eig = (jnp.asarray(self._eigvec(s, r, nf.shape[0], nb))
+               if with_eigvec else None)
         return prepared(g, eig, None, ("stream", nb, eb), 1)
 
     def prepare_batched(self, chunk: Sequence[tuple], batch_size: int,
@@ -582,7 +583,7 @@ class Executor:
             off = 0
             for s, r, nf, _ in gs:
                 n = nf.shape[0]
-                vec[off : off + n] = np.asarray(self._eigvec(s, r, n, n))
+                vec[off : off + n] = self._eigvec(s, r, n, n)
                 off += n
             eig = jnp.asarray(vec)
         return prepared(g, eig, None,
@@ -703,6 +704,11 @@ class Executor:
         eigensolve is the most expensive single prepare stage, so
         repeated shapes must not re-pay it.  Hits/misses land in the
         ``serve_eigvec_cache_total`` counter when a registry is attached.
+
+        The vector is a read-only host ``np.ndarray``: a packed flush
+        copies it into its eigenvector leaf and puts that once with the
+        rest of the batch, so a solve costs no device crossing of its own.
+        Callers that want it on the device put it themselves.
         """
         s_arr = np.ascontiguousarray(s)
         r_arr = np.ascontiguousarray(r)
@@ -715,7 +721,8 @@ class Executor:
             return cached
         from repro.data.pipeline import laplacian_eigvec
 
-        vec = jnp.asarray(laplacian_eigvec(s, r, n, n_pad))
+        vec = laplacian_eigvec(s, r, n, n_pad)
+        vec.flags.writeable = False
         self._eigvec_lru[key] = vec
         if len(self._eigvec_lru) > self._EIGVEC_LRU_SIZE:
             self._eigvec_lru.popitem(last=False)

@@ -988,9 +988,10 @@ class StreamScheduler:
         return attrs
 
     def _eigvecs(self, raws: Sequence[tuple]) -> List[np.ndarray]:
-        """Each graph's Laplacian eigenvector on the host (DGN's input)."""
+        """Each graph's Laplacian eigenvector on the host (DGN's input), left
+        there for ``pack_prepared``'s one put."""
         return [
-            np.asarray(self.executor._eigvec(s, r, nf.shape[0], nf.shape[0]))
+            self.executor._eigvec(s, r, nf.shape[0], nf.shape[0])
             for s, r, nf, _ in (g[:4] for g in raws)
         ]
 

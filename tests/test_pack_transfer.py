@@ -132,6 +132,46 @@ def test_pack_prepared_crosses_to_the_device_once(fill, with_eig, rng,
     assert all(isinstance(x, jax.Array) for x in jax.tree.leaves(prep))
 
 
+@pytest.mark.parametrize("fill", ["one", "several", "full"])
+def test_dgn_flush_eigvec_and_pack_cross_to_the_device_once(fill, rng,
+                                                            monkeypatch):
+    """A DGN flush's eigenvectors stay on the host from the solve to the
+    flush's one put, on an LRU miss and on a hit alike: the eigvec stage
+    runs under a guard against every transfer, explicit ones included
+    (``jnp.asarray`` is explicit, and on the CPU backend reading a device
+    array back is no transfer at all, so ``"disallow"`` alone would let a
+    round trip through); the pack then makes exactly one
+    ``jax.device_put``, and the packed leaf holds the solve's bits."""
+    from repro.data.pipeline import laplacian_eigvec
+
+    budget = BucketBudget(n_pad=128, e_pad=384, g_pad=8)
+    count = {"one": 1, "several": 3, "full": budget.g_pad}[fill]
+    graphs = _molecules(rng, budget, count)
+    sched = StreamScheduler(X.Executor(buckets=DEFAULT_BUCKETS),
+                            with_eigvec=True)
+    want = [laplacian_eigvec(s, r, nf.shape[0], nf.shape[0])
+            for s, r, nf, _ in graphs]
+
+    puts = []
+    real_put = jax.device_put
+
+    def counting_put(*args, **kwargs):
+        puts.append(args)
+        return real_put(*args, **kwargs)
+
+    monkeypatch.setattr(jax, "device_put", counting_put)
+    for lookup in ("miss", "hit"):
+        puts.clear()
+        with jax.transfer_guard("disallow_explicit"):
+            vecs = sched._eigvecs(graphs)
+        with jax.transfer_guard("disallow"):
+            prep, meta = pack_prepared(graphs, budget, eigvecs=vecs)
+        assert len(puts) == 1, lookup
+        assert all(type(v) is np.ndarray for v in vecs), lookup
+        np.testing.assert_array_equal(np.asarray(prep.eigvec),
+                                      pack_eigvecs(want, meta), err_msg=lookup)
+
+
 # ------------------------------------------------------- nothing else moved
 
 

@@ -464,6 +464,41 @@ def test_eigvec_lru_evicts_least_recent(monkeypatch):
     assert len(keys) == 2
 
 
+@pytest.mark.parametrize("n_pad", [8, 16], ids=["unpadded", "padded"])
+def test_eigvec_stays_on_the_host(n_pad):
+    """A miss and a hit both hand back the host solve itself: a read-only
+    float32 ``np.ndarray``, bitwise ``laplacian_eigvec``'s vector."""
+    from repro.data.pipeline import laplacian_eigvec
+    from repro.serve.executor import Executor
+
+    ex = Executor(buckets=((16, 32),))
+    g = graph(seed=41)
+    want = laplacian_eigvec(g[0], g[1], g[2].shape[0], n_pad)
+    miss = ex._eigvec(g[0], g[1], g[2].shape[0], n_pad)
+    hit = ex._eigvec(g[0], g[1], g[2].shape[0], n_pad)
+    assert hit is miss
+    for vec in (miss, hit):
+        assert type(vec) is np.ndarray and vec.dtype == np.float32
+        np.testing.assert_array_equal(vec, want)
+        assert not vec.flags.writeable
+        with pytest.raises(ValueError):
+            vec[0] = 1.0
+
+
+def test_prepare_stream_keeps_a_device_eigvec():
+    """``prepare_stream`` puts the host vector itself, so its batch keeps a
+    device eigenvector leaf with the solve's values."""
+    from repro.data.pipeline import laplacian_eigvec
+    from repro.serve.executor import Executor
+
+    ex = Executor(buckets=((16, 32),))
+    g = graph(seed=42)
+    p = ex.prepare_stream(g, with_eigvec=True)
+    assert isinstance(p.eigvec, jax.Array)
+    np.testing.assert_array_equal(
+        np.asarray(p.eigvec), laplacian_eigvec(g[0], g[1], g[2].shape[0], 16))
+
+
 def test_d2h_span_and_counter(rng):
     """Every harvested run waits under a ``device_wait`` span and
     converts outputs under a ``d2h`` span, and the copy's seconds land in
