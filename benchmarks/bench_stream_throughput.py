@@ -15,17 +15,7 @@ other figure sections):
   * after the warmup pass, a second full sweep triggers zero recompiles
     (``engine.compile_seconds`` does not move).
 
-``--pipeline`` switches to the dispatch-ahead sweep: in-flight depth
-{1, 2, 4} at 0.5x-2x of saturation with the real measured host-pack cost
-folded into the virtual timeline (``host_cost="measured"``), recording
-the pack/device overlap fraction from each point's trace.  At 0.5x load
-it asserts the depth-1 free-host run is equivalent to the serial loop:
-bitwise-equal outputs and the identical flush decision trace
-(rids + reasons; timestamps differ only by re-measured device noise,
-and ``start_s`` is definitionally the dispatch instant there).
-
   PYTHONPATH=src python benchmarks/bench_stream_throughput.py [n_graphs]
-  PYTHONPATH=src python benchmarks/bench_stream_throughput.py --pipeline [n_graphs]
 """
 from __future__ import annotations
 
@@ -37,10 +27,7 @@ import numpy as np
 from repro.data.pipeline import MOLHIV, MoleculeStream
 from repro.gnn import init
 from repro.gnn.models import paper_config
-from repro.obs import Tracer
-from repro.serve.clock import VirtualClock
 from repro.serve.gnn_engine import GNNEngine
-from repro.serve.pipeline import PipelineConfig, overlap_fraction
 from repro.serve.scheduler import StreamScheduler
 
 MODEL = "gin"
@@ -123,101 +110,11 @@ def run(n_graphs: int = 64, strict: bool = True):
     return rows
 
 
-def run_pipeline(n_graphs: int = 64, strict: bool = True):
-    """``--pipeline``: dispatch-ahead depth sweep over offered load."""
-    cfg = paper_config(MODEL)
-    eng = GNNEngine(cfg, init(jax.random.PRNGKey(0), cfg))
-    graphs = MoleculeStream(MOLHIV, seed=0).take(n_graphs)
-
-    serial = StreamScheduler(eng, capacity=CAPACITY, max_wait_s=MAX_WAIT_S)
-    serial.run(graphs, qps=0.0)  # warmup: compiles every rung untimed
-    sat = None
-    for _ in range(2):
-        rep = serial.run(graphs, qps=0.0)
-        if sat is None or rep.compute_s < sat.compute_s:
-            sat = rep
-    cap_gps = sat.num_requests / sat.compute_s
-
-    # -- serial == depth-1 equivalence at 0.25x load: free host cost, same
-    # arrivals.  Flush composition there is deadline/signature-driven (the
-    # device is almost never the gate), so the decision trace must match
-    # exactly and outputs must be bitwise-equal.  Timestamps are excluded
-    # — each run re-measures live device seconds, and pipelined
-    # ``start_s`` is the dispatch instant by definition.  One noisy pass
-    # can still push ``device_free`` over a deadline and shift one bucket
-    # boundary, so the pair retries a bounded number of times; the *exact*
-    # scripted-time equivalence is pinned in tests/test_serve_pipeline.py.
-    eq_qps = 0.25 * cap_gps
-    decisions_equal = outputs_equal = False
-    for _ in range(3):
-        rep_ser = serial.run(graphs, qps=eq_qps)
-        rep_d1 = StreamScheduler(
-            eng, capacity=CAPACITY, max_wait_s=MAX_WAIT_S,
-            pipeline=PipelineConfig(inflight=1, host_cost=None),
-        ).run(graphs, qps=eq_qps)
-        decisions_equal = (
-            [(f.rids, f.reason) for f in rep_ser.flush_log]
-            == [(f.rids, f.reason) for f in rep_d1.flush_log]
-        )
-        outputs_equal = all(
-            np.array_equal(np.asarray(a), np.asarray(b))
-            for a, b in zip(rep_ser.outputs, rep_d1.outputs)
-        )
-        if decisions_equal and outputs_equal:
-            break
-    if strict:
-        assert outputs_equal, "depth-1 pipelined outputs != serial"
-        assert decisions_equal, (
-            "depth-1 free-host flush decisions != serial at 0.5x load"
-        )
-    elif not (outputs_equal and decisions_equal):
-        print(f"# WARNING: depth-1 equivalence not met "
-              f"(outputs={outputs_equal}, decisions={decisions_equal})")
-
-    rows = [{
-        "name": f"stream_{MODEL}_pipe_equiv",
-        "graphs_per_s": round(rep_d1.graphs_per_s, 1),
-        "derived": {
-            "serial_equals_depth1_outputs": outputs_equal,
-            "serial_equals_depth1_decisions": decisions_equal,
-            "offered_qps": round(eq_qps, 1),
-        },
-    }]
-
-    # -- the sweep: real measured host-pack seconds on the virtual
-    # timeline, per depth x load; overlap fraction from each trace
-    tr = Tracer(VirtualClock())
-    for depth in (1, 2, 4):
-        sched = StreamScheduler(
-            eng, capacity=CAPACITY, max_wait_s=MAX_WAIT_S, tracer=tr,
-            pipeline=PipelineConfig(inflight=depth, host_cost="measured"),
-        )
-        for frac in (0.5, 1.0, 2.0):
-            tr.clear()
-            rep = sched.run(graphs, qps=frac * cap_gps)
-            rows.append({
-                "name": f"stream_{MODEL}_pipe_d{depth}_{frac:g}x",
-                "graphs_per_s": round(rep.num_served / rep.makespan_s, 1),
-                "derived": {
-                    "inflight": depth,
-                    "offered_qps": round(frac * cap_gps, 1),
-                    "p50_ms": round(rep.percentile_ms(50), 2),
-                    "p99_ms": round(rep.percentile_ms(99), 2),
-                    "overlap_fraction": round(overlap_fraction(tr), 3),
-                    "mean_batch": round(float(np.mean(rep.batch_sizes)), 2),
-                },
-            })
-    return rows
-
-
 def main(strict: bool = False):
     # tolerate the benchmarks.run driver leaving its section name in argv
     digits = [a for a in sys.argv[1:] if a.isdigit()]
     n = int(digits[0]) if digits else 64
-    if "--pipeline" in sys.argv:
-        rows = run_pipeline(n, strict=strict)
-    else:
-        rows = run(n, strict=strict)
+    rows = run(n, strict=strict)
     for row in rows:
         print(f"{row['name']},{row['graphs_per_s']},{row['derived']}")
     return rows

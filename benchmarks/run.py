@@ -7,7 +7,6 @@ trajectory is tracked across PRs.  Sections:
   fig7   per-model GNN inference latency (engine vs dense-SpMM, stream vs batch)
   stream packed micro-batched streaming vs one-graph mode (QPS sweep)
   slo    SLO-aware admission: overload sweep (p99 holds, goodput plateaus)
-  pipeline  dispatch-ahead execution: modeled speedup vs serial host gap
   fig8   large-graph DGN (Cora/CiteSeer/PubMed sizes)
   fig9   NE/MP pipelining speed-ups (sweep + MolHIV + virtual node)
   table4 per-model resource footprint (params/FLOPs/bytes/VMEM tiles)
@@ -31,7 +30,6 @@ _MODULES = {
     "table4": "bench_table4_resources",
     "stream": "bench_stream_throughput",
     "slo": "bench_slo",
-    "pipeline": "bench_pipeline",
     "quant": "bench_quant",
     "layout": "bench_layout",
     "multitenant": "bench_multitenant",
@@ -42,8 +40,8 @@ _MODULES = {
 
 def main() -> None:
     sections = sys.argv[1:] or [
-        "fig9", "table4", "fig8", "fig7", "stream", "slo", "pipeline",
-        "quant", "layout", "multitenant", "roofline"
+        "fig9", "table4", "fig8", "fig7", "stream", "slo", "quant",
+        "layout", "multitenant", "roofline"
     ]
     unknown = [s for s in sections if s not in _MODULES]
     if unknown:

@@ -119,6 +119,7 @@ def _reference(cfg, params, graphs, cpu, chunk: int = 8):
 
     from repro.core.graph import batch_graphs
     from repro.data.pipeline import laplacian_eigvec
+    from repro.gnn.models import needs_eigvec
     from repro.gnn.reference import apply_dense
 
     dense = jax.jit(apply_dense, static_argnums=2)
@@ -131,7 +132,7 @@ def _reference(cfg, params, graphs, cpu, chunk: int = 8):
         n_pad, e_pad = -(-n // 128) * 128, -(-e // 128) * 128
         g = jax.device_put(batch_graphs(part, n_pad=n_pad, e_pad=e_pad), cpu)
         eig = None
-        if cfg.model == "dgn":
+        if needs_eigvec(cfg):
             vec = np.zeros((n_pad,), np.float32)
             off = 0
             for s, r, nf, _ in part:

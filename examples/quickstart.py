@@ -11,6 +11,7 @@ import numpy as np
 from repro.configs.gengnn_models import GNN_MODELS, get_gnn_config
 from repro.data.pipeline import MOLHIV, MoleculeStream
 from repro.gnn import init
+from repro.gnn.models import needs_eigvec
 from repro.serve.gnn_engine import GNNEngine
 
 
@@ -21,7 +22,7 @@ def main():
         params = init(jax.random.PRNGKey(0), cfg)
         engine = GNNEngine(cfg, params)
         outs, lats, _ = engine.infer_stream(
-            [g[:4] for g in graphs], with_eigvec=(name == "dgn")
+            [g[:4] for g in graphs], with_eigvec=needs_eigvec(cfg)
         )
         print(f"{name:7s} -> {len(outs)} graphs, "
               f"mean latency {np.mean(lats)*1e6:7.0f} us, "

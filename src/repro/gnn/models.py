@@ -35,6 +35,10 @@ from repro.kernels.flash_attention import segment_pairs
 # batch (GPS's global attention, masked by graph).
 GRAPH_ATTENTION = frozenset({"gps"})
 
+# Models that take one Laplacian eigenvector per graph, computed on the host
+# at request time (DGN's directional aggregation).
+HOST_EIGVEC = frozenset({"dgn"})
+
 
 @dataclasses.dataclass(frozen=True)
 class GNNConfig:
@@ -73,6 +77,11 @@ def attention_pairs(cfg: GNNConfig, node_counts, rows: int) -> Optional[tuple]:
     if cfg.model not in GRAPH_ATTENTION:
         return None
     return segment_pairs(node_counts, rows)
+
+
+def needs_eigvec(cfg: GNNConfig) -> bool:
+    """Whether a request for ``cfg`` carries a host Laplacian eigenvector."""
+    return cfg.model in HOST_EIGVEC
 
 
 def paper_config(model: str, virtual_node: bool = False, **kw) -> GNNConfig:

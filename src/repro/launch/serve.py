@@ -59,10 +59,6 @@ def _slo_kwargs(args):
     cycles its classes over the stream round-robin."""
     kw = dict(admit_limit=args.admit_limit, admit_margin=args.admit_margin,
               adapt_ladder=args.adapt_ladder)
-    if args.pipeline:
-        from repro.serve.pipeline import PipelineConfig
-
-        kw["pipeline"] = PipelineConfig(inflight=args.inflight)
     if args.slo_ms:
         if ":" in args.slo_ms:
             kw["slo_by_class"] = {
@@ -210,6 +206,7 @@ def serve_gnn(args):
     from repro.configs.gengnn_models import get_gnn_config
     from repro.data.pipeline import MOLHIV, MoleculeStream
     from repro.gnn import init
+    from repro.gnn.models import needs_eigvec
     from repro.serve.gnn_engine import GNNEngine
 
     cfg = get_gnn_config(args.gnn)
@@ -239,7 +236,7 @@ def serve_gnn(args):
         tracer, registry = _telemetry(args)
         sched = StreamScheduler(
             eng, capacity=args.pack, max_wait_s=args.max_wait_ms * 1e-3,
-            with_eigvec=(args.gnn == "dgn"), tracer=tracer,
+            with_eigvec=needs_eigvec(cfg), tracer=tracer,
             metrics=registry, **_slo_kwargs(args),
         )
         _report_cold_start(args, eng.executor, sched,
@@ -265,7 +262,7 @@ def serve_gnn(args):
     if args.batched:
         outs, per_graph_s = eng.infer_batched(
             graphs, batch_size=args.batch, n_pad=args.batch * 32,
-            e_pad=args.batch * 96, with_eigvec=(args.gnn == "dgn"),
+            e_pad=args.batch * 96, with_eigvec=needs_eigvec(cfg),
         )
         print(f"{args.gnn} batched(bs={args.batch}"
               f"{', mesh=' + str(args.gnn_mesh) if mesh is not None else ''}): "
@@ -273,7 +270,7 @@ def serve_gnn(args):
               f"(compile {eng.compile_seconds:.1f}s excluded)")
         return
     outs, lats, compile_s = eng.infer_stream(
-        [g[:4] for g in graphs], with_eigvec=(args.gnn == "dgn")
+        [g[:4] for g in graphs], with_eigvec=needs_eigvec(cfg)
     )
     print(f"{args.gnn}: {len(outs)} graphs, mean {np.mean(lats)*1e6:.0f} us/graph "
           f"(p50 {np.percentile(lats,50)*1e6:.0f}, p99 {np.percentile(lats,99)*1e6:.0f}; "
@@ -337,15 +334,6 @@ def main():
     ap.add_argument("--adapt-ladder", action="store_true",
                     help="stream: re-fit each signature's bucket-rung "
                          "geometry to the observed flush-size histogram")
-    ap.add_argument("--pipeline", action="store_true",
-                    help="stream: pipelined (dispatch-ahead) execution — "
-                         "flushes dispatch at their deadline while prior "
-                         "flushes are still in flight, host pack overlaps "
-                         "device compute (see docs/SERVING.md)")
-    ap.add_argument("--inflight", type=int, default=2,
-                    help="stream: bound on dispatched-but-unharvested "
-                         "flushes in pipelined mode (1 = serial dispatch "
-                         "order; default 2 = double buffering)")
     ap.add_argument("--gnn-mesh", type=int, default=1,
                     help="GNN: shard node/edge rows over this many devices")
     ap.add_argument("--fused", action="store_true",

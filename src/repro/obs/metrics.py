@@ -101,13 +101,6 @@ CATALOG: Dict[str, Tuple[str, str, Tuple[str, ...]]] = {
         "counter", "Query-key pairs of the per-graph attention of each "
         "flush: within its graphs (real, sum of n^2) and in the tiles the "
         "attention kernel runs (computed)", ("kind",)),
-    # ---- pipeline: dispatch-ahead execution
-    "serve_inflight_depth": (
-        "gauge", "Dispatched-but-unharvested flushes in the pipelined "
-        "in-flight window", ()),
-    "serve_pack_ewma_seconds": (
-        "gauge", "Per-signature host-pack EWMA feeding pipelined admission "
-        "projection", ("sig",)),
     # ---- kernels: dispatch decisions (one per compiled program, at trace time)
     "kernels_dispatch_total": (
         "counter",
@@ -344,5 +337,3 @@ class ServingInstruments:
         self.d2h_seconds = registry.counter("serve_d2h_seconds_total")
         self.eigvec_cache = registry.counter("serve_eigvec_cache_total")
         self.attention_pairs = registry.counter("serve_attention_pairs_total")
-        self.inflight_depth = registry.gauge("serve_inflight_depth")
-        self.pack_ewma = registry.gauge("serve_pack_ewma_seconds")

@@ -46,9 +46,9 @@ lands in its ``.xplane.pb`` on the same clock as the device's ops; while
 none runs the annotation is inert (about a microsecond per span) and
 reads no clock of ours.  Code that holds no tracer (``core/batching.py``)
 and stages the in-memory timeline already models with ``record`` (a
-flush, the pipelined loop's pack) open the same annotation, without an
-in-memory span, through :func:`annotate`.  ``record`` and ``event`` are
-timeline-only: they write nothing to the profiler.
+flush) open the same annotation, without an in-memory span, through
+:func:`annotate`.  ``record`` and ``event`` are timeline-only: they
+write nothing to the profiler.
 """
 from __future__ import annotations
 

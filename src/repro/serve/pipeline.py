@@ -2,137 +2,42 @@
 
 GenGNN's serving claim is that preprocessing-free inference keeps the
 accelerator busy on a live stream; FlowGNN gets there by overlapping data
-movement with compute across its queues.  Our serial loop was the
-opposite: ``Executor.run`` blocked inside the scheduler's event loop, and
-every prepare stage (padding, ``pack_layout``, the Laplacian eigensolve)
-ran on the host *between* device executions — at capacity the device
-idled while the host packed, and the host idled while the device ran.
+movement with compute across its queues.  The scheduler's serial loop is
+the opposite: ``Executor.run`` blocks inside its event loop, and every
+prepare stage (padding, ``pack_layout``, the Laplacian eigensolve) runs
+on the host *between* device executions — at capacity the device idles
+while the host packs, and the host idles while the device runs.
 
-This module owns the two live halves of the fix, and is the **only**
-place in ``serve/`` + ``obs/`` allowed to touch ``threading`` /
-``concurrent.futures`` (``tools/check_engine_singlepath.py`` enforces
-it, the same way it pins the ``time`` module to the executor + clock):
+:class:`PipelinedStream` is the live overlap runner: a double-buffered
+executor-level loop in which a single worker thread runs the
+``prepare_*`` stage (pad + layout + eigvec) for request k+1 and stages it
+onto the device with ``jax.device_put`` while the device runs request k;
+the caller thread dispatches via :meth:`Executor.run_async` (no
+``block_until_ready``) and harvests completions strictly FIFO through a
+bounded in-flight window (default depth 2).  No served path uses it yet;
+it is the template for dispatch-ahead inside the scheduler's one loop.
 
-* :class:`PipelinedStream` — a double-buffered executor-level runner: a
-  single worker thread runs the ``prepare_*`` stage (pad + layout +
-  eigvec) for request k+1 and stages it onto the device with
-  ``jax.device_put`` while the device runs request k; the caller thread
-  dispatches via :meth:`Executor.run_async` (no ``block_until_ready``)
-  and harvests completions strictly FIFO through a bounded in-flight
-  window (default depth 2).
-* :class:`PipelineConfig` — the knob object the scheduler's *modeled*
-  pipelined mode takes (``StreamScheduler(pipeline=...)``).  Under a
-  ``VirtualClock`` the scheduler must stay single-threaded and bitwise
-  deterministic, so it never uses the worker thread: it dispatches and
-  harvests out of order on the virtual timeline, modeling host-pack
-  cost per flush from ``host_cost`` — ``None`` (free host), a scripted
-  constant/sequence (exact sims), or ``"measured"`` (real host seconds
-  read through the executor's clock, folded into the timeline).
+This module is the **only** place in ``serve/`` + ``obs/`` allowed to
+touch ``threading`` / ``concurrent.futures``
+(``tools/check_engine_singlepath.py`` enforces it, the same way it pins
+the ``time`` module to the executor + clock).
 
 Thread discipline: exactly one worker, and it only *prepares*; dispatch,
 harvest, and every executor-cache mutation stay on the caller thread.
 Because the device executes dispatches in order, completions are FIFO by
 construction — harvesting the window front preserves per-request
 response order even though dispatch k+1 happens before k completes.
-
-:func:`overlap_fraction` reports how much host-pack time actually hid
-under device execution, computed from a run's trace spans — the number
-``benchmarks/bench_pipeline.py`` records.
 """
 from __future__ import annotations
 
 import collections
 import concurrent.futures
-import dataclasses
-from typing import Callable, List, Optional, Sequence, Tuple, Union
+from typing import Callable, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
 
 from repro.serve.executor import Executor, PendingRun, PreparedBatch
-
-
-@dataclasses.dataclass(frozen=True)
-class PipelineConfig:
-    """Knobs of the pipelined execution mode.
-
-    inflight:   bound on dispatched-but-unharvested flushes (the
-                in-flight window).  1 = serial dispatch order (the
-                equivalence baseline); 2 = double buffering (default).
-    host_cost:  how the scheduler's modeled pipeline accounts host-pack
-                time per flush on the virtual timeline:
-                  * ``None`` — host work is free on the timeline (pure
-                    dispatch-ahead semantics; the deterministic default);
-                  * a float — constant seconds per flush (exact sims);
-                  * a sequence — scripted per-flush seconds, the last
-                    entry repeating once exhausted (mirrors the
-                    ``scripted_executor`` service-time convention);
-                  * ``"measured"`` — real host seconds measured around
-                    the pack stage through the executor's clock and
-                    folded into the timeline (benchmark honesty on a
-                    live box; no longer bitwise across runs).
-    overlap:    whether the modeled prepare worker packs *ahead* of the
-                device (the pipeline; default).  ``False`` gates each
-                pack on the device going idle — exactly the serial
-                loop's inline-blocking host — which is the baseline a
-                modeled speedup must be measured against:
-                ``PipelineConfig(inflight=1, host_cost=h, overlap=False)``
-                is "the serial path if its host gap were ``h``".
-    """
-
-    inflight: int = 2
-    host_cost: Union[None, str, float, Sequence[float]] = None
-    overlap: bool = True
-
-    def __post_init__(self):
-        if self.inflight < 1:
-            raise ValueError(f"inflight must be >= 1, got {self.inflight}")
-        hc = self.host_cost
-        if hc is None or hc == "measured":
-            return
-        if isinstance(hc, str):
-            raise ValueError(
-                f"host_cost must be None, 'measured', seconds, or a "
-                f"sequence of seconds; got {hc!r}"
-            )
-        seq = hc if isinstance(hc, (list, tuple)) else (hc,)
-        if not seq or any(float(x) < 0 for x in seq):
-            raise ValueError(f"host_cost seconds must be >= 0, got {hc!r}")
-
-    @property
-    def measured(self) -> bool:
-        return self.host_cost == "measured"
-
-    def host_cost_fn(self) -> Optional[Callable[[int], float]]:
-        """Per-flush-index modeled host cost; ``None`` for ``"measured"``
-        (the scheduler then times the real pack stage instead)."""
-        hc = self.host_cost
-        if hc == "measured":
-            return None
-        if hc is None:
-            return lambda i: 0.0
-        if isinstance(hc, (int, float)):
-            const = float(hc)
-            return lambda i: const
-        seq = [float(x) for x in hc]
-        return lambda i: seq[min(i, len(seq) - 1)]
-
-
-def as_pipeline(value) -> Optional[PipelineConfig]:
-    """Normalize the scheduler's ``pipeline=`` argument: ``None``/False
-    = serial (off), True = defaults, an int = that in-flight depth, a
-    :class:`PipelineConfig` = itself."""
-    if value is None or value is False:
-        return None
-    if value is True:
-        return PipelineConfig()
-    if isinstance(value, PipelineConfig):
-        return value
-    if isinstance(value, int):
-        return PipelineConfig(inflight=value)
-    raise ValueError(
-        f"pipeline must be None/bool/int/PipelineConfig, got {value!r}"
-    )
 
 
 class PipelinedStream:
@@ -226,30 +131,3 @@ class PipelinedStream:
             "peak_inflight": peak_inflight,
             "graphs_per_s": len(outputs) / max(wall_s, 1e-12),
         }
-
-
-def overlap_fraction(trace_or_spans) -> float:
-    """Fraction of host-pack span time that overlapped device execution,
-    from a run's trace: ``pack`` spans (host track) against the union of
-    ``device`` spans.  0.0 when no pack time was recorded — a serial run
-    on a `VirtualClock` has zero-width pack markers, so a nonzero value
-    is itself evidence the timeline modeled (or measured) real overlap."""
-    spans = getattr(trace_or_spans, "spans", trace_or_spans)
-    packs = [(s.t0_s, s.t1_s) for s in spans
-             if s.name == "pack" and s.t1_s is not None and s.t1_s > s.t0_s]
-    total = sum(t1 - t0 for t0, t1 in packs)
-    if total <= 0.0:
-        return 0.0
-    devs = sorted((s.t0_s, s.t1_s) for s in spans
-                  if s.name == "device" and s.t1_s is not None)
-    merged: List[Tuple[float, float]] = []
-    for t0, t1 in devs:
-        if merged and t0 <= merged[-1][1]:
-            merged[-1] = (merged[-1][0], max(merged[-1][1], t1))
-        else:
-            merged.append((t0, t1))
-    ov = 0.0
-    for p0, p1 in packs:
-        for d0, d1 in merged:
-            ov += max(0.0, min(p1, d1) - max(p0, d0))
-    return ov / total

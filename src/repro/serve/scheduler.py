@@ -97,7 +97,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from collections import Counter, deque
+from collections import Counter
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
@@ -108,12 +108,11 @@ from repro.core.batching import (
     pack_prepared,
     unpack_outputs,
 )
-from repro.gnn.models import attention_pairs
+from repro.gnn.models import attention_pairs, needs_eigvec
 from repro.obs.metrics import MetricsRegistry, ServingInstruments
 from repro.obs.trace import NULL_TRACER, Tracer, annotate
 from repro.serve.clock import Clock, VirtualClock
 from repro.serve.executor import Executor
-from repro.serve.pipeline import PipelineConfig, as_pipeline
 
 
 def _tenant_label(model: Optional[str]) -> str:
@@ -322,26 +321,6 @@ class _OpenBucket:
         return len(self.requests) >= self.budget.g_pad
 
 
-@dataclasses.dataclass
-class _InFlight:
-    """One dispatched-but-unharvested flush in the pipelined in-flight
-    window.  Every field is fixed at dispatch (the device is serial, so
-    the modeled completion instant is known then); the harvest step only
-    finalizes — response order, flush-log append, trace/metric emission —
-    strictly FIFO off the window front."""
-
-    key: tuple  # (model, priority, sig)
-    bucket: _OpenBucket
-    rung: BucketBudget
-    outs: List[np.ndarray]
-    reason: str
-    at_s: float  # flush decision instant
-    start_s: float  # dispatch instant (host pack done, run_async issued)
-    begin_s: float  # device actually starts (>= start_s under backlog)
-    done_s: float  # begin_s + compute: the completion/harvest instant
-    compute_s: float
-
-
 class StreamScheduler:
     """SLO-aware micro-batching front-end for the serving executor.
 
@@ -354,7 +333,8 @@ class StreamScheduler:
                   this long after it opened (SLO deadlines can tighten
                   an individual bucket further, never loosen it).
     with_eigvec:  compute DGN's Laplacian-eigenvector input per request;
-                  ``"auto"`` resolves per tenant (eigvec iff DGN).
+                  ``"auto"`` resolves per tenant through
+                  ``gnn.models.needs_eigvec``.
     budgets:      explicit per-signature ladders (overrides derivation).
     prewarm:      ``"eager"`` / ``"lazy"`` ladder warm policy (see module
                   docstring); default eager for a single engine, lazy for
@@ -409,28 +389,6 @@ class StreamScheduler:
                   ``VirtualClock`` per ``run``.  Inject a shared clock to
                   chain runs on one timeline, or a ``RealClock`` to stamp
                   live arrivals.
-    pipeline:     pipelined (dispatch-ahead) execution mode.  ``None`` /
-                  ``False`` = the serial event loop (historical
-                  behaviour, bitwise-unchanged); ``True`` = defaults
-                  (in-flight depth 2); an int = that depth; a
-                  ``serve.pipeline.PipelineConfig`` = full control,
-                  including the modeled per-flush host-pack cost.  In
-                  pipelined mode a bucket dispatches at its deadline
-                  whenever the bounded in-flight window has room — the
-                  device need not be free — and completions are
-                  harvested strictly FIFO, so per-request response order
-                  is preserved while host pack for flush k+1 overlaps
-                  device compute for flush k on the (virtual) timeline.
-                  ``FlushRecord.start_s`` is then the *dispatch* instant
-                  (host pack done, ``run_async`` issued), not the device
-                  start; ``done_s`` stays the completion instant.
-                  Admission projection adds a per-signature host-pack
-                  EWMA on top of the serial device-backlog model (with
-                  the default free host cost it reduces exactly to the
-                  serial projection).  Deterministic under
-                  ``VirtualClock``: the loop stays single-threaded and
-                  models the overlap; live threading lives only in
-                  ``serve.pipeline.PipelinedStream``.
     """
 
     def __init__(
@@ -453,7 +411,6 @@ class StreamScheduler:
         tracer: Optional[Tracer] = None,
         metrics: Optional[MetricsRegistry] = None,
         clock: Optional[Clock] = None,
-        pipeline: Union[None, bool, int, PipelineConfig] = None,
     ):
         if capacity < 1:
             raise ValueError("capacity must be >= 1")
@@ -505,19 +462,16 @@ class StreamScheduler:
         self._ladders: Dict[tuple, List[BucketBudget]] = {
             k: sorted(v) for k, v in (budgets or {}).items()
         }
-        self._pipeline = as_pipeline(pipeline)
         # per-signature service-time EWMA (measured flush compute) and the
         # observed ideal-rung-multiple window the adaptive refit consumes
         self._svc_s: Dict[tuple, float] = {}
         self._obs_multiples: Dict[tuple, List[int]] = {}
-        # per-signature host-pack EWMA (pipelined admission projection)
-        self._pack_s: Dict[tuple, float] = {}
 
     # ------------------------------------------------------------ admission
 
     def _needs_eigvec(self, model: Optional[str]) -> bool:
         if self.with_eigvec == "auto":
-            return self.executor.tenant(model).cfg.model == "dgn"
+            return needs_eigvec(self.executor.tenant(model).cfg)
         return bool(self.with_eigvec)
 
     def resolve_slo_s(self, model: Optional[str], priority: int) -> float:
@@ -533,24 +487,6 @@ class StreamScheduler:
         ``service_s``) — the deterministic input to shed decisions and
         deadline tightening."""
         return self._svc_s.get(sig, self.service_s)
-
-    def pack_estimate_s(self, sig: tuple) -> float:
-        """The signature's host-pack EWMA (pipelined mode only; 0.0
-        before the first flush, and identically 0.0 under the default
-        free modeled host cost — which is what makes the pipelined
-        admission projection reduce to the serial one)."""
-        return self._pack_s.get(sig, 0.0)
-
-    def _observe_pack(self, sig: tuple, pack_s: float) -> None:
-        """Fold one flush's host-pack seconds (modeled or measured) into
-        the signature's pack EWMA — same ``svc_alpha`` coefficient as
-        the service estimate."""
-        prev = self._pack_s.get(sig)
-        a = self.svc_alpha
-        self._pack_s[sig] = (pack_s if prev is None
-                             else (1.0 - a) * prev + a * pack_s)
-        if self._mi is not None:
-            self._mi.pack_ewma.set(self._pack_s[sig], sig=f"{sig[0]}x{sig[1]}")
 
     def ladder_multiples(self, sig: tuple) -> List[int]:
         """Current rung geometry of one signature, in base-bucket
@@ -762,9 +698,6 @@ class StreamScheduler:
             # may have been built before this run's clock existed)
             tr.clock = clock
         mi = self._mi
-        if self._pipeline is not None:
-            return self._run_pipelined(requests, clock, t0, compile_before)
-
         open_buckets: Dict[tuple, _OpenBucket] = {}
         outputs: List[Optional[np.ndarray]] = [None] * len(requests)
         latencies = np.full(len(requests), np.nan)
@@ -994,306 +927,3 @@ class StreamScheduler:
             self.executor._eigvec(s, r, nf.shape[0], nf.shape[0])
             for s, r, nf, _ in (g[:4] for g in raws)
         ]
-
-    def _execute_pipelined(self, bucket: _OpenBucket, rung: BucketBudget,
-                           measure_host: bool) -> Tuple[List[np.ndarray], float, float]:
-        """Pack + run + unpack one bucket for the pipelined loop.
-
-        Unlike the serial ``_execute``, pack/unpack are *not* live tracer
-        spans: the pipelined loop records them with modeled timeline
-        intervals instead (the pack span genuinely overlaps the device
-        span there).  The same ``repro.flush`` / ``eigvec`` / ``pack`` /
-        ``unpack`` profiler spans open here as in ``_execute``.  With
-        ``measure_host`` the real host-side pack seconds (eigvec +
-        ``pack_prepared``) are measured through the executor's clock —
-        the only real-time source the serving stack may read — and
-        returned for timeline folding; otherwise the returned pack
-        seconds are 0.0 and the caller's modeled ``host_cost`` governs."""
-        model = bucket.model
-        tenant = self.executor.tenant(model)
-        raws = [r.graph for r in bucket.requests]
-        label = _tenant_label(model)
-        with annotate("flush", **self._flush_attrs(tenant, raws, rung)):
-            t_pack0 = self.executor.clock.now() if measure_host else 0.0
-            vecs = None
-            if self._needs_eigvec(model):
-                with annotate("eigvec", tenant=label, graphs=len(raws)):
-                    vecs = self._eigvecs(raws)
-            with annotate("pack", tenant=label, graphs=len(raws),
-                          rung=rung.g_pad // 2):
-                prep, meta = pack_prepared(raws, rung, eigvecs=vecs,
-                                           with_layout=tenant.share_layout)
-            pack_wall_s = (self.executor.clock.now() - t_pack0
-                           if measure_host else 0.0)
-            out, dt = self.executor.run(prep, model=model)
-            level = "graph" if tenant.cfg.task == "graph" else "node"
-            with annotate("unpack", tenant=label, graphs=len(raws)):
-                outs = unpack_outputs(out, meta, level=level)
-        return outs, dt, pack_wall_s
-
-    def _run_pipelined(self, requests: List[Request], clock: Clock,
-                       t0: float, compile_before: float) -> StreamReport:
-        """Dispatch-ahead event loop (``pipeline=`` mode).
-
-        Differences from the serial loop, and nothing else:
-
-        * the flush gate replaces ``device_free_s`` with the in-flight
-          window: ``eff = max(deadline, slot_free)`` where ``slot_free``
-          is the front completion when the window is full and ``-inf``
-          while it has room — so a bucket dispatches at its deadline even
-          while the device is busy (that is the overlap);
-        * each dispatch threads three modeled resources: the single host
-          prepare worker (``host_free_s`` — packs serialize), the serial
-          device (``device_free_s``), and the window slot.  ``start_s``
-          is the dispatch instant (pack done), ``done_s`` the device
-          completion;
-        * completions are harvested strictly FIFO off the window front —
-          the device executes dispatches in order, so front-first harvest
-          preserves per-request response order by construction.  Harvest
-          finalizes outputs/records/telemetry and never advances the
-          clock;
-        * admission projects host-pack EWMAs on top of the serial
-          device-backlog model (free host cost → bitwise the serial
-          projection).
-
-        Single-threaded and deterministic under ``VirtualClock``: the
-        engine compute runs synchronously at dispatch (clean per-flush
-        ``compute_s``), only its *placement* on the timeline models the
-        pipeline.  Live threaded overlap is ``serve.pipeline``'s job.
-        """
-        cfg = self._pipeline
-        inflight = cfg.inflight
-        cost_fn = cfg.host_cost_fn()  # None => measure real pack seconds
-        tr = self.tracer
-        mi = self._mi
-
-        open_buckets: Dict[tuple, _OpenBucket] = {}
-        outputs: List[Optional[np.ndarray]] = [None] * len(requests)
-        latencies = np.full(len(requests), np.nan)
-        shed_list: List[Shed] = []
-        flush_log: List[FlushRecord] = []
-        window: "deque[_InFlight]" = deque()  # dispatch == completion order
-        device_free_s = t0
-        host_free_s = t0
-        last_done_s = t0
-        queued = 0
-        bucket_seq = 0
-        flush_idx = 0
-
-        def harvest_one() -> None:
-            f = window.popleft()
-            bucket = f.bucket
-            misses = 0
-            for req, out in zip(bucket.requests, f.outs):
-                outputs[req.rid] = out
-                latencies[req.rid] = f.done_s - req.arrival_s
-                if f.done_s > req.deadline_s:
-                    misses += 1
-            model, priority, sig = f.key
-            flush_log.append(FlushRecord(
-                model=model, priority=priority, sig=sig,
-                rids=tuple(r.rid for r in bucket.requests), reason=f.reason,
-                at_s=f.at_s, start_s=f.start_s, done_s=f.done_s,
-                compute_s=f.compute_s, rung_multiple=f.rung.g_pad // 2,
-                misses=misses,
-            ))
-            if tr.enabled:
-                tenant = _tenant_label(model)
-                for req in bucket.requests:
-                    tr.record("queue", req.arrival_s, f.at_s, track="scheduler",
-                              rid=req.rid, tenant=tenant, priority=priority)
-                tr.record("flush", f.at_s, f.done_s, track="scheduler",
-                          tenant=tenant, priority=priority, reason=f.reason,
-                          graphs=len(bucket.requests), sig=str(sig),
-                          rung=f.rung.g_pad // 2)
-                tr.record("unpack", f.done_s, f.done_s, track="host",
-                          tenant=tenant, graphs=len(bucket.requests))
-                for req in bucket.requests:
-                    tr.event("respond", t_s=f.done_s, track="scheduler",
-                             rid=req.rid, latency_s=f.done_s - req.arrival_s,
-                             miss=bool(f.done_s > req.deadline_s))
-            if mi is not None:
-                tenant = _tenant_label(model)
-                pr = str(priority)
-                mi.flushes.inc(reason=f.reason)
-                mi.flush_graphs.observe(len(bucket.requests))
-                mi.served.inc(len(bucket.requests), tenant=tenant, priority=pr)
-                if misses:
-                    mi.deadline_misses.inc(misses, tenant=tenant, priority=pr)
-                for req in bucket.requests:
-                    mi.latency.observe(f.done_s - req.arrival_s,
-                                       tenant=tenant, priority=pr)
-                mi.inflight_depth.set(len(window))
-
-        def harvest_due(now_s: float) -> None:
-            # completions whose modeled finish predates the instant being
-            # processed; harvesting never advances the clock
-            while window and window[0].done_s <= now_s:
-                harvest_one()
-
-        def dispatch(key: tuple, at_s: float, reason: str) -> None:
-            nonlocal device_free_s, host_free_s, last_done_s, queued, flush_idx
-            if at_s > clock.now():
-                clock.advance_to(at_s)
-            harvest_due(at_s)
-            bucket = open_buckets.pop(key)
-            queued -= len(bucket.requests)
-            rung = bucket.rung()
-            outs, dt, pack_wall = self._execute_pipelined(
-                bucket, rung, measure_host=cost_fn is None)
-            pack_s = pack_wall if cost_fn is None else cost_fn(flush_idx)
-            flush_idx += 1
-            # one prepare worker: packs serialize behind host_free_s;
-            # without overlap the pack also waits for the device to go
-            # idle (the serial loop's inline-blocking host, the modeled
-            # baseline for speedup claims)
-            pack_begin = max(at_s, host_free_s)
-            if not cfg.overlap:
-                pack_begin = max(pack_begin, device_free_s)
-            start_s = pack_begin + pack_s  # dispatch instant
-            host_free_s = start_s
-            if len(window) >= inflight:
-                # a budget flush can land on a full window: the dispatch
-                # stalls until the front completion frees its slot
-                start_s = max(start_s, window[0].done_s)
-                harvest_one()
-            begin_s = max(start_s, device_free_s)  # the device is serial
-            done_s = begin_s + dt
-            device_free_s = done_s
-            last_done_s = max(last_done_s, done_s)
-            model, priority, sig = key
-            self._observe_flush(sig, bucket, dt)
-            self._observe_pack(sig, pack_s)
-            window.append(_InFlight(
-                key=key, bucket=bucket, rung=rung, outs=outs, reason=reason,
-                at_s=at_s, start_s=start_s, begin_s=begin_s, done_s=done_s,
-                compute_s=dt,
-            ))
-            if tr.enabled:
-                tenant = _tenant_label(model)
-                tr.event("dispatch", t_s=start_s, track="scheduler",
-                         tenant=tenant, priority=priority, reason=reason,
-                         graphs=len(bucket.requests), sig=str(sig),
-                         inflight=len(window))
-                tr.record("pack", pack_begin, start_s, track="host",
-                          tenant=tenant, graphs=len(bucket.requests),
-                          rung=rung.g_pad // 2)
-                tr.record("device", begin_s, done_s, track="device",
-                          tenant=tenant, graphs=len(bucket.requests),
-                          compute_s=dt)
-            if mi is not None:
-                mi.queue_depth.set(queued)
-                mi.open_buckets.set(len(open_buckets))
-                mi.inflight_depth.set(len(window))
-
-        idx = 0
-        while idx < len(requests) or open_buckets:
-            next_arrival_s = (requests[idx].arrival_s if idx < len(requests)
-                              else math.inf)
-            # the dispatch gate: with window room a bucket's deadline
-            # alone governs (dispatch-ahead — the device need not be
-            # free); a full window makes the front completion the
-            # earliest instant a new flush could enter it.  Priority then
-            # bucket age break effective-instant ties, same total order
-            # as the serial loop.
-            slot_free_s = (window[0].done_s if len(window) >= inflight
-                           else -math.inf)
-            best_key, best_eff, best_rank = None, math.inf, None
-            for k, b in open_buckets.items():
-                eff = max(b.deadline_s, slot_free_s)
-                rank = (eff, b.priority, b.seq)
-                if best_rank is None or rank < best_rank:
-                    best_key, best_eff, best_rank = k, eff, rank
-            if best_key is not None and best_eff <= next_arrival_s:
-                dispatch(best_key, best_eff,
-                         "deadline" if idx < len(requests) else "drain")
-                continue
-            req = requests[idx]
-            idx += 1
-            clock.advance_to(req.arrival_s)
-            now = req.arrival_s
-            harvest_due(now)
-            # ---- admission: the serial projection plus host-pack EWMAs
-            # (each open bucket's future flush passes through the single
-            # prepare worker before it can occupy the device).  With the
-            # default free modeled host cost every pack estimate is 0.0
-            # and this is bitwise the serial projection; device_free_s
-            # already carries dispatched-ahead flushes.
-            sig = self.executor.bucket_for(req.n, req.e)
-            svc_est = self.service_estimate_s(sig)
-            pending = sum(
-                self.service_estimate_s(k[2]) + self.pack_estimate_s(k[2])
-                for k in open_buckets)
-            own_open = (req.model, req.priority, sig) in open_buckets
-            projected = (max(0.0, device_free_s - now) + pending
-                         + (0.0 if own_open
-                            else svc_est + self.pack_estimate_s(sig)))
-            if mi is not None:
-                mi.requests.inc(tenant=_tenant_label(req.model),
-                                priority=str(req.priority))
-            shed_reason = None
-            if (math.isfinite(req.slo_s)
-                    and projected > req.slo_s * self.admit_margin):
-                shed_reason = "backlog"
-            elif self.admit_limit is not None and queued >= self.admit_limit:
-                shed_reason = "queue_full"
-            if shed_reason is not None:
-                shed_list.append(Shed(
-                    rid=req.rid, model=req.model, priority=req.priority,
-                    reason=shed_reason, at_s=now,
-                    projected_delay_s=projected, slo_s=req.slo_s,
-                ))
-                if tr.enabled:
-                    tr.event("shed", t_s=now, track="scheduler", rid=req.rid,
-                             tenant=_tenant_label(req.model),
-                             priority=req.priority, reason=shed_reason,
-                             projected_delay_s=projected)
-                if mi is not None:
-                    mi.shed.inc(tenant=_tenant_label(req.model),
-                                priority=str(req.priority),
-                                reason=shed_reason)
-                continue
-            sig, ladder = self.ladder_for(req)
-            key = (req.model, req.priority, sig)
-            bucket = open_buckets.get(key)
-            if bucket is not None and not bucket.admits(req):
-                dispatch(key, now, "budget")
-                bucket = None
-            if bucket is None:
-                bucket = _OpenBucket(ladder, now, self.max_wait_s,
-                                     model=req.model, priority=req.priority,
-                                     seq=bucket_seq)
-                bucket_seq += 1
-                open_buckets[key] = bucket
-            bucket.add(req, service_est_s=svc_est)
-            queued += 1
-            if tr.enabled:
-                tr.event("admit", t_s=now, track="scheduler", rid=req.rid,
-                         tenant=_tenant_label(req.model),
-                         priority=req.priority, bucket=str(sig),
-                         projected_delay_s=projected)
-            if mi is not None:
-                mi.admitted.inc(tenant=_tenant_label(req.model),
-                                priority=str(req.priority))
-                mi.queue_depth.set(queued)
-                mi.open_buckets.set(len(open_buckets))
-            if bucket.full:
-                dispatch(key, now, "budget")
-
-        while window:
-            harvest_one()
-        if last_done_s > clock.now():
-            clock.advance_to(last_done_s)
-        if mi is not None:
-            mi.queue_depth.set(0)
-            mi.open_buckets.set(0)
-            mi.inflight_depth.set(0)
-        return StreamReport(
-            latencies_s=latencies,
-            outputs=outputs,
-            makespan_s=max(last_done_s - (requests[0].arrival_s if requests else t0),
-                           1e-12),
-            compile_s=self.executor.untimed_seconds - compile_before,
-            shed=shed_list,
-            flush_log=flush_log,
-        )

@@ -9,6 +9,7 @@ import jax
 import numpy as np
 import pytest
 
+from conftest import scripted_executor
 from repro.core.batching import BucketBudget, pack_graphs, unpack_outputs
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -157,6 +158,59 @@ def test_latencies_include_queueing_delay(engine, scheduler):
     # last-served request covers all earlier compute
     assert float(rep.latencies_s.max()) >= rep.compute_s * 0.9
     assert rep.makespan_s > 0 and rep.graphs_per_s > 0
+
+
+# ----------------------------------------------------------- option checks
+
+
+@pytest.mark.parametrize("kwargs,run_kwargs,match", [
+    (dict(capacity=0), None, "capacity must be >= 1"),
+    (dict(prewarm="warm"), None, "prewarm must be 'eager' or 'lazy'"),
+    (dict(admit_limit=0), None, "admit_limit must be >= 1"),
+    (dict(refit_every=0), None, "refit_every must be >= 1"),
+    (dict(max_rungs=1), None, r"max_rungs must be >= 2"),
+    ({}, dict(priorities=[0]), r"priorities \(1\) must tag every graph \(2\)"),
+], ids=["capacity", "prewarm", "admit_limit", "refit_every", "max_rungs",
+        "priorities"])
+def test_scheduler_rejects_invalid_options(kwargs, run_kwargs, match):
+    from repro.serve.scheduler import StreamScheduler
+
+    with pytest.raises(ValueError, match=match):
+        sched = StreamScheduler(scripted_executor(), **kwargs)
+        sched.run(_graphs(2), **(run_kwargs or {}))
+
+
+# ------------------------------------------------------- host eigenvectors
+
+
+@pytest.mark.parametrize("model,vn", [
+    ("gcn", False), ("gin", False), ("gin", True), ("gat", False),
+    ("pna", False), ("dgn", False), ("gps", False),
+], ids=["gcn", "gin", "gin_vn", "gat", "pna", "dgn", "gps"])
+def test_auto_eigvec_follows_the_model(model, vn):
+    """A ``with_eigvec="auto"`` scheduler solves host eigenvectors for a
+    tenant exactly when ``needs_eigvec`` names its model, and packs
+    ``eigvecs=None`` otherwise."""
+    from repro.gnn.models import needs_eigvec, paper_config
+    from repro.serve.scheduler import StreamScheduler
+
+    cfg = paper_config(model, virtual_node=vn)
+    ex = scripted_executor()
+    ex.tenants["default"].cfg = cfg
+    solves, seen = [], []
+    solve = ex._eigvec
+    ex._eigvec = lambda *a: solves.append(a) or solve(*a)
+    run = ex.run
+    ex.run = lambda p, model=None: seen.append(p.eigvec) or run(p, model)
+    graphs = _graphs(3, seed=5)
+    rep = StreamScheduler(ex, capacity=2, with_eigvec="auto").run(graphs)
+    assert rep.num_served == len(graphs) and seen
+    if needs_eigvec(cfg):
+        assert len(solves) == len(graphs)
+        assert all(v is not None for v in seen)
+    else:
+        assert solves == []
+        assert all(v is None for v in seen)
 
 
 _SHARDED_PACKED_SCRIPT = r"""

@@ -97,11 +97,12 @@ def _workload(model: str, budget, n_graphs: int = 4):
     from repro.core import batching as B
     from repro.data.pipeline import MOLHIV, MoleculeStream
     from repro.gnn import init
+    from repro.gnn.models import needs_eigvec
 
     cfg = get_gnn_config(model)
     params = init(jax.random.PRNGKey(0), cfg)
     graphs = [g[:4] for g in MoleculeStream(MOLHIV, seed=7).take(n_graphs)]
-    need_eig = model == "dgn"
+    need_eig = needs_eigvec(cfg)
     eigvecs = None
     if need_eig:
         from repro.data.pipeline import laplacian_eigvec

@@ -47,13 +47,13 @@ second persistence format with its own (unfingerprinted) invalidation
 story.  The real calls live behind ``runtime/compat.py``; the
 serve/obs walk keeps everyone else out.
 
-Since the pipelined execution mode landed, a third rule rides the same
-walk: **threading is single-path too**.  Any import of ``threading`` /
-``_thread`` / ``concurrent`` (including ``concurrent.futures``) outside
-``serve/pipeline.py`` fails — the pipelined prepare/dispatch worker is
-the one sanctioned threading surface, and everything else (scheduler,
-executor, clock, telemetry) must stay single-threaded so VirtualClock
-simulations remain bitwise deterministic.  ``serve/executor.py`` is NOT
+A third rule rides the same walk: **threading is single-path too**.
+Any import of ``threading`` / ``_thread`` / ``concurrent`` (including
+``concurrent.futures``) outside ``serve/pipeline.py`` fails — the
+``PipelinedStream`` prepare worker is the one sanctioned threading
+surface, and everything else (scheduler, executor, clock, telemetry)
+must stay single-threaded so VirtualClock simulations remain bitwise
+deterministic.  ``serve/executor.py`` is NOT
 exempt from this rule: it is walked too, with only its historical
 timing/compile allowances.
 
