@@ -129,10 +129,8 @@ def child(state_path: str) -> None:
     steady_us = {}
     for name in names:
         prep, _ = pack_prepared(graphs[:4], budget, with_layout=True)
-        p = ex.prepare_packed(prep.graph, budget, eigvec=prep.eigvec,
-                              layout=prep.layout, model=name)
-        ex.warm(p, model=name)
-        best = min(ex.run(p, model=name)[1] for _ in range(STEADY_REPS))
+        ex.warm(prep, model=name)
+        best = min(ex.run(prep, model=name)[1] for _ in range(STEADY_REPS))
         steady_us[name] = round(best * 1e6, 1)
 
     print(_MARK + json.dumps({

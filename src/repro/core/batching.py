@@ -16,8 +16,9 @@ node-offset slicing (node-level), never by masking heuristics.
 Everything here is host-side (numpy) construction — the packed ``Graph``
 enters the jit boundary exactly like a single padded graph does, so the
 engine's compiled buckets are reused across packed batches.
-``pack_prepared`` builds the whole pack-time payload on the host and
-crosses to the device with one ``jax.device_put``.
+``pack_prepared`` builds the whole pack-time payload on the host, writes
+it into one buffer (``core.wire``) and crosses to the device with one
+``jax.device_put`` of that one array.
 """
 from __future__ import annotations
 
@@ -138,10 +139,12 @@ def pack_prepared(
     sorts; the paper's convert-once-at-ingest, §3.4).  Returns
     ``(prepared, meta)`` — ``meta`` is the exact unpack bookkeeping.
 
-    Every leaf is built in numpy, the warm signature included; the batch
-    then crosses to the default device in one ``jax.device_put``, with no
-    device round trip in between.  The layout plan is built under the
-    ``repro.layout`` profiler span.
+    Every leaf is built in numpy, the warm signature included, and written
+    into one int32 buffer; that buffer is the one array the batch's
+    ``jax.device_put`` moves to the default device, with no device round
+    trip in between (a put pays per array, not per byte).  The served
+    program splits it at the manifest's static offsets.  The layout plan
+    is built under the ``repro.layout`` profiler span.
     """
     from repro.serve import executor as X  # deferred: serve imports core
 

@@ -138,6 +138,19 @@ def from_numpy(
     e_pad: Optional[int] = None,
 ) -> Graph:
     """Build a single padded ``Graph`` from raw COO numpy arrays."""
+    return jax.device_put(host_from_numpy(senders, receivers, node_feat,
+                                          edge_feat, n_pad=n_pad, e_pad=e_pad))
+
+
+def host_from_numpy(
+    senders: np.ndarray,
+    receivers: np.ndarray,
+    node_feat: np.ndarray,
+    edge_feat: Optional[np.ndarray] = None,
+    n_pad: Optional[int] = None,
+    e_pad: Optional[int] = None,
+) -> Graph:
+    """:func:`from_numpy` with numpy leaves: nothing touches the device."""
     n = node_feat.shape[0]
     e = senders.shape[0]
     n_pad = n_pad or n
@@ -158,13 +171,13 @@ def from_numpy(
     edge_mask = np.arange(e_pad) < e
     graph_id = np.where(node_mask, 0, 0).astype(np.int32)
     return Graph(
-        node_feat=jnp.asarray(nf),
-        edge_index=jnp.asarray(ei),
-        edge_feat=jnp.asarray(ef),
-        node_mask=jnp.asarray(node_mask),
-        edge_mask=jnp.asarray(edge_mask),
-        graph_id=jnp.asarray(graph_id),
-        n_graph=jnp.asarray(1, dtype=jnp.int32),
+        node_feat=nf,
+        edge_index=ei,
+        edge_feat=ef,
+        node_mask=node_mask,
+        edge_mask=edge_mask,
+        graph_id=graph_id,
+        n_graph=np.asarray(1, np.int32),
     )
 
 

@@ -82,7 +82,6 @@ from collections import OrderedDict
 from typing import Callable, Dict, Optional, Sequence, Set, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro import runtime as RT
@@ -95,6 +94,7 @@ from repro.serve.clock import Clock, RealClock
 from repro.core import batching as B
 from repro.core import graph as G
 from repro.core import layout as LY
+from repro.core import wire as W
 from repro.gnn import models as M
 
 DEFAULT_BUCKETS: Sequence[tuple] = ((32, 96), (64, 192), (128, 384), (256, 768))
@@ -106,18 +106,18 @@ DEFAULT_BUCKETS: Sequence[tuple] = ((32, 96), (64, 192), (128, 384), (256, 768))
 
 
 def trace_signature(graph: G.Graph, eigvec=None, layout=None) -> tuple:
-    """The warm/compile signature of one prepared input: presence flags for
-    the optional operands plus (shape, dtype) of **every** leaf.
+    """The warm/compile signature of one prepared input: its wire manifest
+    (``core.wire.manifest_of``), which holds the presence of the optional
+    operands and the (shape, dtype) and buffer offset of **every** leaf.
 
     This is the single signature function for every mode.  The stream mode
     used to key warmth on ``("eig", with_eigvec)`` alone, so a mid-stream
     dtype change (int edge features after float ones in the same bucket)
     recompiled inside the timed region; keying on the leaves closes that.
+    The leading ``"wire"`` keeps it apart from the signatures of programs
+    that took the leaves one by one, in the AOT cache too.
     """
-    leaves = jax.tree.leaves((graph, eigvec, layout))
-    return (("eig", eigvec is not None), ("lay", layout is not None)) + tuple(
-        (tuple(v.shape), str(v.dtype)) for v in leaves
-    )
+    return ("wire", W.manifest_of(graph, eigvec, layout))
 
 
 def params_signature(params) -> tuple:
@@ -136,30 +136,50 @@ def params_signature(params) -> tuple:
 @dataclasses.dataclass(frozen=True)
 class PreparedBatch:
     """One batch, fully staged for the executor: the padded (possibly
-    packed) graph, its optional eigenvector input and layout plan, plus the
-    static routing facts — bucket key, graph-slot count, warm signature.
+    packed) graph, its optional eigenvector input and layout plan, written
+    into one buffer of int32 words (``core.wire``), plus the static facts —
+    the buffer's manifest, bucket key, graph-slot count, warm signature.
 
     Produced by the ``prepare_*`` family (and by
     ``core.batching.pack_prepared`` at pack time); consumed by
-    :meth:`Executor.warm` / :meth:`Executor.run`.  A pytree: the graph /
-    eigvec / layout leaves are data, the routing facts are static metadata.
+    :meth:`Executor.warm` / :meth:`Executor.run`.  A pytree whose one data
+    leaf is ``wire``, so a ``jax.device_put`` of it moves one array; the
+    served program splits it.  ``graph`` / ``eigvec`` / ``layout`` read the
+    leaves back, eagerly, for callers outside the program.
     """
 
-    graph: G.Graph
-    eigvec: Optional[jax.Array]
-    layout: Optional[LY.GraphLayout]
+    wire: jax.Array
+    manifest: tuple = dataclasses.field(metadata=dict(static=True))
     bucket_key: tuple = dataclasses.field(metadata=dict(static=True))
     num_graphs: int = dataclasses.field(metadata=dict(static=True))
     signature: tuple = dataclasses.field(metadata=dict(static=True))
 
+    def unwire(self):
+        """``(graph, eigvec, layout)`` sliced out of the buffer."""
+        return W.unwire(self.wire, self.manifest)
+
+    @property
+    def graph(self) -> G.Graph:
+        return self.unwire()[0]
+
+    @property
+    def eigvec(self) -> Optional[jax.Array]:
+        return self.unwire()[1]
+
+    @property
+    def layout(self) -> Optional[LY.GraphLayout]:
+        return self.unwire()[2]
+
 
 def prepared(graph: G.Graph, eigvec, layout, bucket_key: tuple,
              num_graphs: int) -> PreparedBatch:
-    """Assemble a ``PreparedBatch``, computing its warm signature."""
+    """Assemble a host ``PreparedBatch``: write the leaves into one numpy
+    buffer and compute its warm signature.  Callers put it on the device
+    (one array); a device leaf handed in is read back first."""
+    buf, manifest = W.wire(graph, eigvec, layout)
     return PreparedBatch(
-        graph=graph, eigvec=eigvec, layout=layout, bucket_key=bucket_key,
-        num_graphs=num_graphs,
-        signature=trace_signature(graph, eigvec, layout),
+        wire=buf, manifest=manifest, bucket_key=bucket_key,
+        num_graphs=num_graphs, signature=("wire", manifest),
     )
 
 
@@ -466,7 +486,10 @@ class Executor:
             )
 
             @jax.jit
-            def run(params, g: G.Graph, eigvec, layout):
+            def run(params, p: PreparedBatch):
+                # the flush crosses as one buffer (p's only data leaf);
+                # its leaves come back here, at the manifest's offsets
+                g, eigvec, layout = p.unwire()
                 g = self._constrain_graph(g)
                 if eigvec is not None:
                     eigvec = RT.logical_constraint(eigvec, ("nodes",))
@@ -495,7 +518,7 @@ class Executor:
         with the resolved XLA compiler options applied.  A flag set the
         backend rejects raises: serving on other options than the table
         names would hide what runs on the device."""
-        lowered = cb.fn.lower(tenant.params, p.graph, p.eigvec, p.layout)
+        lowered = cb.fn.lower(tenant.params, p)
         cb.lowered_count += 1
         if flags:
             return lowered.compile(compiler_options=dict(flags))
@@ -541,7 +564,7 @@ class Executor:
             cb.executables[sig] = exe
             compile_dt = self.clock.now() - t0
             t1 = self.clock.now()
-            jax.block_until_ready(exe(tenant.params, p.graph, p.eigvec, p.layout))
+            jax.block_until_ready(exe(tenant.params, p))
             warm_dt = self.clock.now() - t1
         cb.warm.add(sig)
         cb.compile_s += compile_dt
@@ -564,10 +587,9 @@ class Executor:
         once on device — the single timed sort of the forward)."""
         s, r, nf, ef = raw[:4]
         nb, eb = self.bucket_for(nf.shape[0], len(s))
-        g = G.from_numpy(s, r, nf, ef, n_pad=nb, e_pad=eb)
-        eig = (jnp.asarray(self._eigvec(s, r, nf.shape[0], nb))
-               if with_eigvec else None)
-        return prepared(g, eig, None, ("stream", nb, eb), 1)
+        g = G.host_from_numpy(s, r, nf, ef, n_pad=nb, e_pad=eb)
+        eig = self._eigvec(s, r, nf.shape[0], nb) if with_eigvec else None
+        return jax.device_put(prepared(g, eig, None, ("stream", nb, eb), 1))
 
     def prepare_batched(self, chunk: Sequence[tuple], batch_size: int,
                         n_pad: int, e_pad: int,
@@ -576,18 +598,17 @@ class Executor:
         graphs, build per-graph eigenvectors at the packed node offsets
         (host-side, before the timed region)."""
         gs = [(g[0], g[1], g[2], g[3]) for g in chunk]
-        g = G.batch_graphs(gs, n_pad=n_pad, e_pad=e_pad)
+        g = G.host_batch_graphs(gs, n_pad=n_pad, e_pad=e_pad)
         eig = None
         if with_eigvec:
-            vec = np.zeros((n_pad,), np.float32)
+            eig = np.zeros((n_pad,), np.float32)
             off = 0
             for s, r, nf, _ in gs:
                 n = nf.shape[0]
-                vec[off : off + n] = self._eigvec(s, r, n, n)
+                eig[off : off + n] = self._eigvec(s, r, n, n)
                 off += n
-            eig = jnp.asarray(vec)
-        return prepared(g, eig, None,
-                        ("batched", n_pad, e_pad, batch_size), batch_size)
+        return jax.device_put(prepared(
+            g, eig, None, ("batched", n_pad, e_pad, batch_size), batch_size))
 
     def prepare_packed(self, packed: G.Graph, budget, eigvec=None,
                        layout=None, model: Optional[str] = None) -> PreparedBatch:
@@ -597,11 +618,12 @@ class Executor:
         (zero on-device sorts in the flushed program); when absent and the
         tenant shares layouts, the host plan is built here — the plan
         always travels with its batch, never a sort inside the program.
-        Host leaves (``core.batching.pack_graphs``' numpy graph, the host
-        plan) cross to the device in one put, before any timed region.
+        The leaves (``core.batching.pack_graphs``' numpy graph, the host
+        plan) cross to the device as one buffer in one put, before any
+        timed region; a device graph is read back to be written into it.
         """
         if eigvec is not None:
-            eigvec = jnp.asarray(eigvec, jnp.float32)
+            eigvec = np.asarray(eigvec, np.float32)
         if layout is None and self.tenant(model).share_layout:
             layout = B.pack_layout(packed)
         return jax.device_put(prepared(
@@ -666,7 +688,7 @@ class Executor:
                 # deserialized); cb.fn remains the lowering source/fallback
                 fn = cb.executables.get(sig, cb.fn)
                 t0 = self.clock.now()
-                out = fn(tenant.params, p.graph, p.eigvec, p.layout)
+                out = fn(tenant.params, p)
         return PendingRun(self, out, tenant, p, t0)
 
     def run(self, p: PreparedBatch,

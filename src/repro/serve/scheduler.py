@@ -100,6 +100,7 @@ import math
 from collections import Counter
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
+import jax
 import numpy as np
 
 from repro.core.batching import (
@@ -893,9 +894,13 @@ class StreamScheduler:
                              graphs=len(raws)):
                     vecs = self._eigvecs(raws)
             with tr.span("pack", track="host", tenant=label,
-                         graphs=len(raws), rung=rung.g_pad // 2):
+                         graphs=len(raws), rung=rung.g_pad // 2) as pack:
                 prep, meta = pack_prepared(raws, rung, eigvecs=vecs,
                                            with_layout=tenant.share_layout)
+                if tr.enabled:
+                    put = jax.tree.leaves(prep)
+                    pack.note(put_arrays=len(put),
+                              put_bytes=sum(x.nbytes for x in put))
             out, dt = self.executor.run(prep, model=model)
             level = "graph" if tenant.cfg.task == "graph" else "node"
             with tr.span("unpack", track="host", tenant=label,

@@ -345,3 +345,42 @@ def test_restarted_process_serves_with_zero_traces(rng, tmp_path):
                        text=True, env=env)
     assert r.returncode == 0, r.stdout + r.stderr
     assert "RESTART_OK" in r.stdout
+
+
+# ------------------------------------------------- the one-buffer program
+
+
+def test_a_leaf_wise_signature_misses_a_wire_entry(rng, tmp_path):
+    """An entry for a program that took the flush leaf by leaf (the form
+    before the one buffer), stored under that form's signature, is never
+    loaded for the one-buffer program: the wire signature keys it apart,
+    so the executor compiles fresh and serves the right bits."""
+    from repro.gnn.models import forward_program
+
+    cfg = _reduced_config("gcn")
+    params = init(KEY, cfg)
+    graphs = _raw_graphs(rng, k=1)
+    want, _ = _serve(tmp_path / "fresh", cfg, params, graphs)
+
+    cache = AOTCache(str(tmp_path / "shared"))
+    ex = Executor(buckets=((16, 32),), aot_cache=cache)
+    tenant = ex.register("m", cfg, params)
+    p = ex.prepare_stream(graphs[0])
+    graph, eigvec, layout = p.unwire()
+    leaf_wise = (("eig", eigvec is not None), ("lay", layout is not None)) + \
+        tuple((tuple(v.shape), str(v.dtype))
+              for v in jax.tree.leaves((graph, eigvec, layout)))
+    assert p.signature != leaf_wise
+    fp = ex._fingerprint(ex._compiler_options(tenant, p.bucket_key))
+    old = jax.jit(forward_program(cfg, num_graphs=1)).lower(
+        tenant.params, graph, eigvec, layout).compile()
+    old_key = (repr(tenant.program_key), p.bucket_key, p.num_graphs,
+               (tenant.params_sig,) + leaf_wise)
+    assert cache.store(old_key, fp, old)
+
+    out, _ = ex.run(p, model="m")
+    assert cache.stats == {"hit": 0, "miss": 1, "stale": 0}
+    assert ex.lowered_count == 1
+    np.testing.assert_array_equal(out[:1], want)
+    assert len(cache.entries()) == 2
+    assert cache.load(old_key, fp) is not None  # the old entry is intact

@@ -87,7 +87,10 @@ def test_scripted_run_emits_exact_span_boundaries():
     (pack,), (unpack,) = (spans_by_name(tracer, n) for n in ("pack", "unpack"))
     assert (pack.t0_s, pack.t1_s) == (A1, A1)
     assert (unpack.t0_s, unpack.t1_s) == (A1, A1)
-    assert dict(pack.attrs) == {"tenant": "default", "graphs": 2, "rung": 1}
+    # the flush crosses as one array: the graph's seven leaves in 128-word
+    # segments of one int32 buffer (no plan: the scripted tenant shares none)
+    assert dict(pack.attrs) == {"tenant": "default", "graphs": 2, "rung": 1,
+                                "put_arrays": 1, "put_bytes": 5120}
 
     (fl,) = spans_by_name(tracer, "flush")
     assert (fl.t0_s, fl.t1_s) == (A1, DONE)

@@ -1,18 +1,24 @@
-"""The packed flush is built on the host and crosses to the device once.
+"""The packed flush is built on the host and crosses to the device once,
+as one buffer.
 
 ``core.batching.pack_prepared`` assembles the padded graph, the packed
 eigenvector, the layout plan and the warm signature from numpy arrays,
-then makes one ``jax.device_put``.  These tests hold it to that:
+writes every leaf into one int32 buffer (``core.wire``), then makes one
+``jax.device_put`` of that one array.  These tests hold it to that:
 
   * no implicit host-to-device transfer happens while packing (under
     ``jax.transfer_guard_host_to_device("disallow")``), exactly one
-    ``jax.device_put`` is made per call, and ``host_layout`` only ever
-    sees numpy arrays;
-  * the ``PreparedBatch`` is the one a construction through the device
-    gives (``batch_graphs``, then the plan read back from its device graph;
-    ``Executor.prepare_packed`` of a device graph):
-    every leaf bitwise equal, with equal shapes, dtypes, weak types and
-    warm signature, and the plan equal to the on-device sort's;
+    ``jax.device_put`` of exactly one array is made per call, and
+    ``host_layout`` only ever sees numpy arrays;
+  * the graph, eigenvector and plan read back from the buffer are the
+    ones a construction through the device gives (``batch_graphs``, then
+    the plan read back from its device graph; ``Executor.prepare_packed``
+    of a device graph): every leaf bitwise equal, with equal shapes,
+    dtypes, weak types and warm signature, and the plan equal to the
+    on-device sort's;
+  * the buffer round-trips every bit pattern, and the served program,
+    which splits it, gives the bits of ``forward_program`` called on the
+    leaves one by one;
   * served through the scheduler after ``prewarm_ladders``, no program is
     lowered again, and all six models serve the same bits from either
     construction.
@@ -22,11 +28,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import graph as G
 from repro.core import layout as LY
-from repro.core.batching import BucketBudget, pack_eigvecs, pack_graphs, pack_prepared
+from repro.core import wire as W
+from repro.core.batching import (
+    BucketBudget, pack_eigvecs, pack_graphs, pack_layout, pack_prepared,
+)
 from repro.core.graph import batch_graphs
+from repro.data.pipeline import MOLHIV, MoleculeStream
 from repro.gnn import init
-from repro.gnn.models import paper_config
+from repro.gnn.models import GNNConfig, forward_program, paper_config
 from repro.serve import executor as X
 from repro.serve.executor import DEFAULT_BUCKETS
 from repro.serve.gnn_engine import GNNEngine
@@ -64,10 +75,11 @@ def _eigvecs(rng, graphs):
     return [rng.normal(size=(g[2].shape[0],)).astype(np.float32) for g in graphs]
 
 
-def _device_graph_prepared(graphs, budget, vecs, share_layout=True):
-    """The construction with a device round trip: the batch put on the
-    device leaf by leaf, the eigenvector through ``jnp.asarray``, the plan
-    read back from the device graph and put there again."""
+def _device_graph_leaves(graphs, budget, vecs, share_layout=True):
+    """The construction with a device round trip: ``(graph, eigvec,
+    layout)`` with the batch put on the device leaf by leaf, the
+    eigenvector through ``jnp.asarray``, the plan read back from the
+    device graph and put there again."""
     g = batch_graphs([tuple(x[:4]) for x in graphs],
                      n_pad=budget.n_pad, e_pad=budget.e_pad)
     _, meta = pack_graphs(graphs, budget)
@@ -75,9 +87,7 @@ def _device_graph_prepared(graphs, budget, vecs, share_layout=True):
                                                 jnp.float32)
     layout = (jax.tree.map(jnp.asarray, LY.host_layout(g))
               if share_layout else None)
-    return X.prepared(g, eig, layout,
-                      ("packed", budget.n_pad, budget.e_pad, budget.g_pad),
-                      budget.g_pad)
+    return g, eig, layout
 
 
 def _leaf_facts(tree):
@@ -86,12 +96,20 @@ def _leaf_facts(tree):
                      for x in leaves]
 
 
-def _assert_same_batch(got, want, msg):
-    assert got.bucket_key == want.bucket_key, msg
-    assert got.num_graphs == want.num_graphs, msg
-    assert got.signature == want.signature, msg
-    assert _leaf_facts(got) == _leaf_facts(want), msg
-    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+def _assert_same_batch(got, want, budget, msg):
+    """``got``, a device ``PreparedBatch``, carries ``want``'s
+    ``(graph, eigvec, layout)`` leaves: the views read back from its one
+    buffer are bitwise equal, with equal shapes, dtypes, weak types and
+    absent parts, and its signature is theirs."""
+    assert got.bucket_key == ("packed", budget.n_pad, budget.e_pad,
+                              budget.g_pad), msg
+    assert got.num_graphs == budget.g_pad, msg
+    assert got.signature == X.trace_signature(*want), msg
+    (buf,) = jax.tree.leaves(got)
+    assert isinstance(buf, jax.Array) and buf.dtype == jnp.int32, msg
+    views = got.unwire()
+    assert _leaf_facts(views) == _leaf_facts(want), msg
+    for a, b in zip(jax.tree.leaves(views), jax.tree.leaves(want)):
         assert isinstance(a, jax.Array), msg
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=msg)
 
@@ -125,11 +143,14 @@ def test_pack_prepared_crosses_to_the_device_once(fill, with_eig, rng,
         prep, meta = pack_prepared(graphs, budget, eigvecs=vecs)
 
     assert len(puts) == 1
+    (sent,) = jax.tree.leaves(puts[0])
+    assert type(sent) is np.ndarray and sent.dtype == np.int32
     assert len(layout_inputs) == 1
     assert all(type(x) is np.ndarray for x in layout_inputs[0])
     assert meta.num_graphs == count
     assert (prep.eigvec is not None) == with_eig
-    assert all(isinstance(x, jax.Array) for x in jax.tree.leaves(prep))
+    (buf,) = jax.tree.leaves(prep)
+    assert isinstance(buf, jax.Array) and buf.shape == sent.shape
 
 
 @pytest.mark.parametrize("fill", ["one", "several", "full"])
@@ -167,6 +188,7 @@ def test_dgn_flush_eigvec_and_pack_cross_to_the_device_once(fill, rng,
         with jax.transfer_guard("disallow"):
             prep, meta = pack_prepared(graphs, budget, eigvecs=vecs)
         assert len(puts) == 1, lookup
+        assert len(jax.tree.leaves(puts[0])) == 1, lookup
         assert all(type(v) is np.ndarray for v in vecs), lookup
         np.testing.assert_array_equal(np.asarray(prep.eigvec),
                                       pack_eigvecs(want, meta), err_msg=lookup)
@@ -182,10 +204,10 @@ def test_pack_prepared_equals_device_graph_construction(model, rung, rng):
         graphs = _molecules(rng, rung, count)
         vecs = _eigvecs(rng, graphs) if model == "dgn" else None
         got, _ = pack_prepared(graphs, rung, eigvecs=vecs)
-        want = _device_graph_prepared(graphs, rung, vecs)
+        want = _device_graph_leaves(graphs, rung, vecs)
         msg = f"{model} {rung} graphs={count}"
-        _assert_same_batch(got, want, msg)
-        device_plan = LY.build_layout(want.graph)
+        _assert_same_batch(got, want, rung, msg)
+        device_plan = LY.build_layout(want[0])
         for a, b in zip(jax.tree.leaves(got.layout),
                         jax.tree.leaves(device_plan)):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
@@ -197,8 +219,8 @@ def test_pack_prepared_without_layout_equals_device_graph_construction(rng):
     graphs = _molecules(rng, budget, 3)
     got, _ = pack_prepared(graphs, budget, with_layout=False)
     assert got.layout is None
-    want = _device_graph_prepared(graphs, budget, None, share_layout=False)
-    _assert_same_batch(got, want, "no plan")
+    want = _device_graph_leaves(graphs, budget, None, share_layout=False)
+    _assert_same_batch(got, want, budget, "no plan")
 
 
 # --------------------------------------------------- served, no new program
@@ -236,8 +258,104 @@ def test_served_bits_and_programs_unchanged(name, rng):
         batch_graphs(graphs[:2], n_pad=budget.n_pad, e_pad=budget.e_pad),
         budget, eigvec=None if vecs is None else pack_eigvecs(vecs, meta),
         model=eng.name)
-    _assert_same_batch(new, old, name)
+    _assert_same_batch(new, old.unwire(), budget, name)
+    np.testing.assert_array_equal(np.asarray(new.wire), np.asarray(old.wire))
     out_new, _ = ex.run(new, model=eng.name)
     out_old, _ = ex.run(old, model=eng.name)
     np.testing.assert_array_equal(out_new, out_old, err_msg=name)
     assert ex.lowered_count == lowered, "the two constructions differ in program"
+
+
+# ------------------------------------------------------------ the wire
+
+
+def _odd_floats(shape, rng):
+    """float32 values with every awkward bit pattern: NaNs with payloads
+    (quiet and signalling, either sign), -0.0, infinities, subnormals."""
+    bits = np.array([0x7FC00001, 0xFFC12345, 0x7F800001, 0xFFBFFFFF,
+                     0x80000000, 0x7F800000, 0xFF800000, 0x00000001],
+                    np.uint32).view(np.float32)
+    x = rng.normal(size=shape).astype(np.float32).reshape(-1)
+    x[: min(x.size, bits.size)] = bits[: x.size]
+    return x.reshape(shape)
+
+
+def _assert_bitwise(got, want, msg):
+    """Same tree (absent parts included), shapes, dtypes and bytes."""
+    assert jax.tree.structure(got) == jax.tree.structure(want), msg
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        a, b = np.asarray(a), np.asarray(b)
+        assert (a.shape, a.dtype) == (b.shape, b.dtype), msg
+        assert a.tobytes() == b.tobytes(), msg
+
+
+@pytest.mark.parametrize("parts", ["graph", "eigvec", "plan", "eigvec+plan",
+                                   "plan+static", "float16"])
+def test_wire_round_trips_every_bit(parts, rng):
+    """``unwire(wire(x)) == x`` bitwise, eagerly and inside a jitted
+    program: float32 NaN payloads and -0.0, bool masks, the 0-d
+    ``n_graph``, absent eigenvector, plan and model statics."""
+    budget = BucketBudget(n_pad=64, e_pad=192, g_pad=4)
+    graphs = _molecules(rng, budget, 3)
+    g, meta = pack_graphs(graphs, budget)
+    dtype = np.float16 if parts == "float16" else np.float32
+    g = G.Graph(**{**vars(g),
+                   "node_feat": _odd_floats(g.node_feat.shape, rng).astype(dtype),
+                   "edge_feat": _odd_floats(g.edge_feat.shape, rng)})
+    eig = (_odd_floats((budget.n_pad,), rng) if "eigvec" in parts else None)
+    layout = pack_layout(g) if "plan" in parts else None
+    if parts == "plan+static":
+        layout = LY.GraphLayout(**{**vars(layout),
+                                   "gcn_inv_sqrt": _odd_floats((64,), rng)})
+    want = (g, eig, layout)
+
+    buf, manifest = W.wire(*want)
+    assert type(buf) is np.ndarray and buf.dtype == np.int32
+    assert buf.size == W.wire_words(manifest)
+    assert all(seg[0] % W.ALIGN == 0 for _, seg in manifest if seg)
+    segments = dict(manifest)
+    assert (segments["eigvec"] is None) == (eig is None)
+    assert (segments["layout.perm"] is None) == (layout is None)
+    assert (segments["layout.gcn_inv_sqrt"] is None) == (parts != "plan+static")
+    assert segments["layout.pna_scalers"] is None
+    assert segments["graph.n_graph"][1] == () == g.n_graph.shape
+    assert manifest == W.manifest_of(*want)
+
+    _assert_bitwise(W.unwire(buf, manifest), want, f"{parts} eager")
+    traced = jax.jit(lambda b: W.unwire(b, manifest))(buf)
+    _assert_bitwise(traced, want, f"{parts} jitted")
+
+
+def _small_config(model):
+    if model == "gps":
+        return GNNConfig(model="gps", num_layers=2, hidden=32, heads=2,
+                         head_features=16, out_dim=5, pe_steps=16, pe_dim=8,
+                         ffn_hidden=64)
+    return paper_config(model, num_layers=2, hidden=16)
+
+
+@pytest.mark.parametrize("model", ["gin", "dgn", "gps"])
+def test_served_program_equals_a_leaf_wise_call(model):
+    """The served program splits the flush's one buffer inside itself; its
+    outputs are bitwise those of ``forward_program`` called on the same
+    flush's leaves one by one (the form before the buffer)."""
+    from repro.data.pipeline import laplacian_eigvec
+
+    cfg = _small_config(model)
+    ex = X.Executor(buckets=DEFAULT_BUCKETS)
+    tenant = ex.register(model, cfg, init(KEY, cfg))
+    graphs = [g[:4] for g in MoleculeStream(MOLHIV, seed=3).take(5)]
+    budget = BucketBudget(n_pad=256, e_pad=768, g_pad=8)
+    vecs = ([laplacian_eigvec(s, r, nf.shape[0], nf.shape[0])
+             for s, r, nf, _ in graphs] if model == "dgn" else None)
+
+    prep, meta = pack_prepared(graphs, budget, eigvecs=vecs)
+    served, _ = ex.run(prep, model=model)
+
+    packed, _ = pack_graphs(graphs, budget)
+    eig = None if vecs is None else pack_eigvecs(vecs, meta)
+    leaves = jax.device_put((packed, eig, pack_layout(packed)))
+    program = jax.jit(forward_program(cfg, num_graphs=budget.g_pad))
+    leaf_wise = np.asarray(program(tenant.params, *leaves))
+    assert served.shape == (budget.g_pad, cfg.out_dim)
+    np.testing.assert_array_equal(served, leaf_wise, err_msg=model)

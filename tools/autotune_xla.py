@@ -143,13 +143,11 @@ def _measure(model: str, budget, flag_sets: dict, reps: int) -> dict:
         )
         cfg, params, prep = _workload(model, budget)
         ex.register(model, cfg, params)
-        p = ex.prepare_packed(prep.graph, budget, eigvec=prep.eigvec,
-                              layout=prep.layout, model=model)
-        ex.warm(p, model=model)
+        ex.warm(prep, model=model)
         best = float("inf")
         for _ in range(reps):
             t0 = time.perf_counter()
-            ex.run(p, model=model)
+            ex.run(prep, model=model)
             best = min(best, time.perf_counter() - t0)
         results[name] = best
     return results

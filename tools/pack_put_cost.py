@@ -1,20 +1,24 @@
 """Time the parts of ``core.batching.pack_prepared`` on one device.
 
 A flush's pack stage builds the padded batch, the packed eigenvector, the
-layout plan and the warm signature in numpy, then crosses to the device
-with one ``jax.device_put`` of the ``PreparedBatch`` pytree.  This script
-times each part apart, for a flush of 1 and of 6 MolHIV-statistics
-molecules (DGN's inputs, so all 13 leaves cross), and beside them a put
-of the same bytes joined into one buffer.  Every time is the median of
+layout plan and the warm signature in numpy, writes them into one int32
+buffer (``core.wire``), then crosses to the device with one
+``jax.device_put`` of that buffer.  This script times each part apart, for
+a flush of 1 and of 6 MolHIV-statistics molecules (DGN's inputs, so all
+13 leaves are written), and beside them the put of the same 13 leaves as
+a pytree, the form before the buffer.  Every time is the median of
 ``--reps`` calls, in ms:
 
 * ``pad``: ``pack_graphs`` (numpy padding); ``eig``: ``pack_eigvecs``;
-  ``layout``: ``pack_layout``; ``prepared``: ``PreparedBatch`` and its
-  signature;
-* ``put``: ``jax.device_put`` of the pytree, returned without waiting;
-  ``put_wait``: the same put, then ``jax.block_until_ready``;
+  ``layout``: ``pack_layout``; ``prepared``: ``PreparedBatch``, its
+  buffer and its signature;
+* ``put``: ``jax.device_put`` of the 13 leaves as a pytree, returned
+  without waiting; ``put_wait``: the same put, then
+  ``jax.block_until_ready``;
 * ``one_buffer_put_wait``: a put and wait of one uint8 array holding the
   bytes of all the leaves (joined beforehand, outside the timing);
+* ``wire_put_wait``: ``pack_prepared``'s own put, of the
+  ``PreparedBatch`` whose one leaf is the int32 buffer, then the wait;
 * ``pack_prepared``: the whole call, then ``jax.block_until_ready``.
 
 The last line of standard output is one JSON object.  Run it from the
@@ -70,23 +74,27 @@ def measure(graphs, reps: int) -> dict:
     layout = pack_layout(packed)
     key = ("packed", budget.n_pad, budget.e_pad, budget.g_pad)
     prep = prepared(packed, eig, layout, key, budget.g_pad)
-    leaves = jax.tree.leaves(prep)
+    tree = (packed, eig, layout)
+    leaves = jax.tree.leaves(tree)
     joined = np.concatenate([np.ascontiguousarray(x).view(np.uint8).ravel()
                              for x in leaves])
     return {
         "rung": [budget.n_pad, budget.e_pad, budget.g_pad],
         "leaves": len(leaves),
         "bytes": int(joined.size),
+        "wire_bytes": int(prep.wire.nbytes),
         "pad": _median_ms(lambda: pack_graphs(graphs, budget), reps),
         "eig": _median_ms(lambda: pack_eigvecs(eigs, meta), reps),
         "layout": _median_ms(lambda: pack_layout(packed), reps),
         "prepared": _median_ms(
             lambda: prepared(packed, eig, layout, key, budget.g_pad), reps),
-        "put": _median_ms(lambda: jax.device_put(prep), reps),
+        "put": _median_ms(lambda: jax.device_put(tree), reps),
         "put_wait": _median_ms(
-            lambda: jax.block_until_ready(jax.device_put(prep)), reps),
+            lambda: jax.block_until_ready(jax.device_put(tree)), reps),
         "one_buffer_put_wait": _median_ms(
             lambda: jax.block_until_ready(jax.device_put(joined)), reps),
+        "wire_put_wait": _median_ms(
+            lambda: jax.block_until_ready(jax.device_put(prep)), reps),
         "pack_prepared": _median_ms(
             lambda: jax.block_until_ready(pack_prepared(graphs, budget, eigs)[0]),
             reps),
